@@ -29,7 +29,8 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["DEFAULT_RULES", "axis_rules", "shard", "current_rules",
-           "resolve_spec", "mesh_axis_sizes", "ShardingRuleDropped"]
+           "resolve_spec", "mesh_axis_sizes", "axis_size",
+           "ShardingRuleDropped"]
 
 Axis = Union[None, str, Tuple[str, ...]]
 
@@ -87,7 +88,8 @@ def mesh_axis_sizes(mesh: Mesh) -> Dict[str, int]:
     return dict(zip(mesh.axis_names, mesh.devices.shape))
 
 
-def _axis_size(sizes: Dict[str, int], ax: Axis) -> int:
+def axis_size(sizes: Dict[str, int], ax: Axis) -> int:
+    """Devices along ``ax``: one mesh axis, or the product of a tuple."""
     if isinstance(ax, (tuple, list)):
         n = 1
         for a in ax:
@@ -113,7 +115,7 @@ def resolve_spec(rules: Dict[str, Axis], sizes: Dict[str, int],
     for dim, name in zip(shape, logical_axes):
         ax = rules.get(name) if isinstance(name, str) else None
         if ax is not None:
-            n = _axis_size(sizes, ax)
+            n = axis_size(sizes, ax)
             if dim % n != 0:
                 phys_ax = ax if isinstance(ax, str) else tuple(ax)
                 key = (name, phys_ax, n, dim)

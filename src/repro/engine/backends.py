@@ -8,16 +8,15 @@ One datapath, three executions:
             fixed-point MACs in int32, works for every scheme/rounding,
             differentiable via STE.
   pallas    fused TPU kernel (repro.kernels): Scheme.TILED only, runs
-            interpret=True off-TPU.  With prequant weights it dispatches
-            the sidecar-consuming kernel variant that skips in-kernel
-            weight quantization entirely.
+            in the Pallas interpreter on the CPU backend.  With prequant
+            weights it dispatches the sidecar-consuming kernel variant
+            that skips in-kernel weight quantization entirely.
 
 ``select_backend`` honours ``policy.backend`` (or the legacy
 ``use_kernel`` flag) but falls back to ``emulated`` when the requested
 backend cannot execute the policy faithfully — e.g. pallas with a paper
-scheme, stochastic rounding, or an int16 prequant mantissa.  This folds
-the previously scattered ``use_kernel`` / ``interpret=not _on_tpu()``
-dispatch decisions into one place.
+scheme, stochastic rounding, or an int16 prequant mantissa (with
+``strict=True`` it refuses instead).
 
 External backends (future: GPU Triton, int8 XLA dot) register with
 :func:`register_backend`.

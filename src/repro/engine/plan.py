@@ -40,6 +40,8 @@ import types
 from typing import Any, Dict, Iterable, Optional, Tuple, Union
 
 import jax
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.bfp import Rounding, Scheme
 from repro.core.packed import is_packed, unpack_prequant
@@ -54,7 +56,7 @@ from repro.engine.core import _grad_vjp, conv_and_tap, gemm_and_tap
 from repro.engine.policy_map import PolicyLike, PolicyMap, resolve_policy
 from repro.grad.paths import GradSpec, grad_path, resolve_grad_policy
 
-__all__ = ["Site", "Plan", "bind", "unpack_packed"]
+__all__ = ["Site", "Plan", "BoundForward", "bind", "unpack_packed"]
 
 
 def unpack_packed(params: Any) -> Any:
@@ -78,6 +80,46 @@ def unpack_packed(params: Any) -> Any:
     return jax.tree_util.tree_map(
         lambda l: unpack_prequant(l) if is_packed(l) else l,
         params, is_leaf=is_packed)
+
+
+class BoundForward:
+    """``fn(params, x, *args)`` jitted with the ARRAY leaves of
+    ``params`` passed as arguments on every call.  Closed-over arrays
+    would be embedded in each compiled program as constants: the whole
+    model, once per batch bucket, and inside the compile-cache key.
+    Non-array leaves (e.g. GoogLeNet's static widths) stay Python values.
+
+    With ``mesh``, ``fn`` runs under ``jax.shard_map`` with ``x`` split
+    over ``batch_axis`` and the arrays replicated (placed on the mesh
+    once, here).  ``jit=False`` runs eagerly.
+    """
+
+    def __init__(self, fn, params, *, mesh=None, batch_axis=None,
+                 jit: bool = True):
+        leaves, treedef = jax.tree_util.tree_flatten(params)
+        is_arr = [isinstance(l, (jax.Array, np.ndarray)) for l in leaves]
+
+        def run(arrays, x, *args):
+            it = iter(arrays)
+            tree = treedef.unflatten(
+                [next(it) if a else l for l, a in zip(leaves, is_arr)])
+            return fn(tree, x, *args)
+
+        self.arrays = [l for l, a in zip(leaves, is_arr) if a]
+        if mesh is not None:
+            run = jax.shard_map(run, mesh=mesh,
+                                in_specs=(P(), P(batch_axis)),
+                                out_specs=P(batch_axis), check_vma=False)
+            self.arrays = jax.device_put(self.arrays,
+                                         NamedSharding(mesh, P()))
+        self._run = jax.jit(run) if jit else run
+
+    def __call__(self, x, *args):
+        return self._run(self.arrays, x, *args)
+
+    def lower(self, x, *args):
+        """``jax.jit(...).lower`` for input ``x`` (AOT compile/inspect)."""
+        return self._run.lower(self.arrays, x, *args)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -124,9 +166,9 @@ class Plan:
         #: per-plan fallback-warning dedup for unbound-path dispatch, so
         #: one plan's downgrades never mute another's
         self._warned: set = set()
-        #: per-plan cache of jitted forwards, keyed by apply function —
-        #: every consumer binding the same plan to the same model shares
-        #: one traced callable (see :meth:`jit_forward`)
+        #: per-plan cache of jitted forwards, keyed by (apply function,
+        #: mesh, batch axis) — every consumer binding the same plan to the
+        #: same model shares one traced callable (see :meth:`jit_forward`)
         self._jit_cache: Dict[Any, Any] = {}
 
     def __repr__(self) -> str:
@@ -210,25 +252,52 @@ class Plan:
                                 path=path, warned=self._warned,
                                 out_policy=out_policy)
 
-    def jit_forward(self, apply_fn):
+    def jit_forward(self, apply_fn, mesh=None, batch_axis="data"):
         """A jitted ``apply_fn(plan.params, x, plan)``, cached per
-        ``apply_fn`` on this plan.
+        ``(apply_fn, mesh, batch_axis)`` on this plan.
 
         This is how a bound plan is REUSED across jit'd callables: N
         serve engines (or batch buckets, or benchmark drivers) bound to
         the same plan get the SAME callable object back, so they share
         one trace-cache — jax retraces per input shape (each batch
-        bucket compiles once), never per consumer.  The plan and its
-        pre-quantized params ride the closure; extra positional args
-        (e.g. a model's ``training`` flag) pass through.
+        bucket compiles once), never per consumer.  The plan rides the
+        closure; its params are an ARGUMENT of the jitted function (see
+        :class:`BoundForward`).  Extra positional args (e.g. a model's
+        ``training`` flag) pass through.
+
+        With ``mesh``, the forward runs under ``jax.shard_map`` with the
+        batch split over ``batch_axis`` and the params replicated (placed
+        on the mesh once, here): Pallas kernels cannot be partitioned
+        automatically, and per-shard execution is exact for row-local
+        activation blocks.  Sites whose activation exponent spans the
+        whole batch (EQ2/EQ4) are refused when that axis has more than
+        one device — their numerics would become per-shard.
         """
-        fn = self._jit_cache.get(apply_fn)
+        key = (apply_fn, mesh, batch_axis if mesh is not None else None)
+        fn = self._jit_cache.get(key)
         if fn is None:
-            def fwd(x, *args, _apply=apply_fn):
-                return _apply(self.params, x, self, *args)
-            fn = jax.jit(fwd)
-            self._jit_cache[apply_fn] = fn
+            def fwd(params, x, *args, _apply=apply_fn):
+                return _apply(params, x, self, *args)
+            if mesh is not None:
+                self._check_row_local(mesh, batch_axis)
+            fn = BoundForward(fwd, self.params, mesh=mesh,
+                              batch_axis=batch_axis)
+            self._jit_cache[key] = fn
         return fn
+
+    def _check_row_local(self, mesh, batch_axis) -> None:
+        from repro.dist.sharding import axis_size
+        shards = axis_size(mesh.shape, batch_axis)
+        shared = sorted(
+            p for p, s in self._sites.items()
+            if s.policy is not None and s.policy.quantize_inputs
+            and s.policy.scheme in (Scheme.EQ2, Scheme.EQ4))
+        if shards > 1 and shared:
+            raise ValueError(
+                f"sites {shared} share one activation exponent across the "
+                f"batch (EQ2/EQ4); split over {shards} {batch_axis!r} "
+                f"shards it would become per-shard.  Serve a row-local "
+                f"scheme (TILED, EQ3, EQ5) on a multi-device mesh.")
 
     def describe(self) -> str:
         """Human-readable site table (examples / serving admission logs)."""

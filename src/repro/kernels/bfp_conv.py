@@ -48,10 +48,11 @@ reshape [OW, stride, C] and keep phase 0 — exact for any static stride.
 
 Grid: (B, OHp/t_oh, OCp/bn).  The K reduction is an in-kernel static
 loop (n_k tiles), so no cross-step accumulator scratch is needed.  VMEM
-sizing note: each program holds the full [Hp, Wp, C] input plane plus
-[t_oh*OW, Kp] patch rows — fine for the interpret-mode CI and for
-real CNN tails; very large early layers would want a row-windowed DMA
-variant (future work, see DESIGN.md §3).
+sizing: each program holds the full [Hp, Wp, C] input plane (C padded to
+128 lanes) plus [t_oh*OW, Kp] patch rows, and the call raises the
+scoped-VMEM limit to what those blocks need (:func:`_vmem_limit`,
+~68 MiB at vgg16's 224² layers against a 16 MiB default).  A
+row-windowed input DMA would need less; it is not built.
 """
 from __future__ import annotations
 
@@ -61,6 +62,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.bfp_matmul import (_block_format, _mantissa_dtype,
                                       _tile_dot, resolve_dot_impl)
@@ -76,9 +78,8 @@ def _patch_rows(x_ref, *, kh: int, kw: int, stride: int, t_oh: int,
     for di in range(kh):
         # output rows oh0..oh0+t_oh-1 need input rows oh0*s+di + s*r:
         # one dynamic-start slice of s*t_oh rows, then keep phase 0.
-        rows = pl.load(x_ref, (pl.ds(0, 1), pl.ds(oh0 * stride + di,
-                                                  stride * t_oh),
-                               slice(None), slice(None)))
+        rows = x_ref[pl.ds(0, 1), pl.ds(oh0 * stride + di, stride * t_oh),
+                     :, :]
         rows = rows.reshape(t_oh, stride, rows.shape[2], c)[:, 0]
         for dj in range(kw):
             # columns dj + s*i, i < OW: static slice + phase-0 reshape
@@ -170,15 +171,13 @@ def _make_conv_kernel(*, kh, kw, stride, t_oh, ow, bk, n_k, l_i, l_w,
             # channel chunk) — identical math, identical accumulator
             # values as the two-step store-f32-then-prequant_act path.
             ob, bq = out_q
-            ms, ss = [], []
+            ms = []
             for t in range(bn // bq):
                 m, step = _block_format(acc[:, t * bq:(t + 1) * bq], ob,
                                         axis=1, mdtype=jnp.int8)
                 ms.append(m)
-                ss.append(step)
+                os_ref[t] = step.reshape(t_oh, ow, 1)
             om_ref[...] = jnp.concatenate(ms, axis=1).reshape(
-                1, t_oh, ow, -1)
-            os_ref[...] = jnp.concatenate(ss, axis=1).reshape(
                 1, t_oh, ow, -1)
 
     return kernel
@@ -208,6 +207,34 @@ def _check_conv(x_shape, kp, ocp, *, kh, kw, stride, t_oh, ohp, ow, bk,
         if bn % out_block:
             raise ValueError(f"epilogue out_block={out_block} must divide "
                              f"bn={bn}")
+
+
+#: Ceiling on the conv kernels' scoped VMEM: v5e holds 128 MiB of VMEM,
+#: and the rest is left to Mosaic's internal scratch.
+_VMEM_CAP = 100 * 2 ** 20
+#: Room for the kernel body's values (quantized tiles, slabs, partial
+#: products) beyond the blocks and patch rows: the default scoped budget.
+_VMEM_BODY = 16 * 2 ** 20
+
+
+def _vmem_bytes(shape, dtype) -> int:
+    """VMEM footprint of one block: the minor dim pads to 128 lanes and
+    the second-minor to the dtype's sublane tile (8 rows of 32 bits)."""
+    item = jnp.dtype(dtype).itemsize
+    sub = 8 * 4 // item
+    *lead, r, c = shape
+    return (math.prod(lead) * (-(-r // sub) * sub) * (-(-c // 128) * 128)
+            * item)
+
+
+def _vmem_limit(blocks, rows: int, kp: int) -> int:
+    """Scoped-VMEM limit for one conv call: every block double-buffered,
+    the f32 patch rows [rows, Kp], and the body's default budget.  The
+    whole padded input plane is one block, so early layers (224² with C
+    padded to 128 lanes) need ~3x the default 16 MiB limit."""
+    need = (2 * sum(_vmem_bytes(s, d) for s, d in blocks)
+            + _vmem_bytes((rows, kp), jnp.float32) + _VMEM_BODY)
+    return min(_VMEM_CAP, need)
 
 
 def _out_q(out_bits, out_block, bn):
@@ -244,29 +271,45 @@ def _conv_call(x_ops, w_ops, *, kh, kw, stride, t_oh, ohp, ow, bn, bk,
                                  lambda bb, i, j: (bb, i, 0, j))
         out_shape = jax.ShapeDtypeStruct((b, ohp, ow, ocp), jnp.float32)
     else:
+        # epilogue steps leave chunk-major [B, OCp/bq, OHp, OW, 1]: a
+        # (.., OW, bn/bq) block of the NHWC step layout breaks Mosaic's
+        # rule that a block's last two dims divide by (8, 128) or equal
+        # the array's whenever bn < OCp
         bq = out_q[1]
         out_specs = [
             pl.BlockSpec((1, t_oh, ow, bn), lambda bb, i, j: (bb, i, 0, j)),
-            pl.BlockSpec((1, t_oh, ow, bn // bq),
-                         lambda bb, i, j: (bb, i, 0, j)),
+            pl.BlockSpec((pl.Squeezed(), bn // bq, t_oh, ow, 1),
+                         lambda bb, i, j: (bb, j, i, 0, 0)),
         ]
         out_shape = [
             jax.ShapeDtypeStruct((b, ohp, ow, ocp), jnp.int8),
-            jax.ShapeDtypeStruct((b, ohp, ow, ocp // bq), jnp.float32),
+            jax.ShapeDtypeStruct((b, ocp // bq, ohp, ow, 1), jnp.float32),
         ]
 
     kernel = _make_conv_kernel(kh=kh, kw=kw, stride=stride, t_oh=t_oh,
                                ow=ow, bk=bk, n_k=n_k, l_i=l_i, l_w=l_w,
                                x_pq=x_pq, w_pq=w_pq, mode=mode,
                                pipeline=pipeline, out_q=out_q)
-    return pl.pallas_call(
+    blocks = [((hp, wp, c), x_ops[0].dtype), ((kp, bn), w_ops[0].dtype),
+              ((t_oh * ow, bn), jnp.float32)]
+    if x_pq:
+        blocks.append(((hp, wp, c // bk), jnp.float32))
+    if w_pq:
+        blocks.append(((n_k, bn), jnp.float32))
+    out = pl.pallas_call(
         kernel,
         grid=(b, ohp // t_oh, ocp // bn),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(blocks, t_oh * ow, kp)),
         interpret=interpret,
     )(*x_ops, *w_ops)
+    if out_q is None:
+        return out
+    m, s = out
+    return m, s[..., 0].transpose(0, 2, 3, 1)
 
 
 _STATIC = ("kh", "kw", "stride", "t_oh", "ohp", "ow", "bn", "bk", "l_i",

@@ -171,12 +171,13 @@ def _requant_store(acc: jax.Array, om_ref, os_ref, *, out_bits: int,
     """Epilogue: block-format the fp32 accumulator per (row, out_block
     column chunk) and store int8 mantissas + power-of-two steps — the
     activation-prequant wire format, bit-identical to storing f32 and
-    running core.prequant.prequant_act on it."""
+    running core.prequant.prequant_act on it.  ``os_ref`` is the
+    chunk-major step block [bn/out_block, bm, 1] (see :func:`_matmul_call`)."""
     for t in range(acc.shape[1] // out_block):
         chunk = acc[:, t * out_block:(t + 1) * out_block]
         m, step = _block_format(chunk, out_bits, axis=1, mdtype=jnp.int8)
         om_ref[:, t * out_block:(t + 1) * out_block] = m
-        os_ref[:, t:t + 1] = step
+        os_ref[t] = step
 
 
 def _make_matmul_kernel(*, l_i: int, l_w: int, n_k: int, x_pq: bool,
@@ -304,12 +305,23 @@ def _matmul_call(x_ops, w_ops, *, b, k, n, l_i, l_w, bm, bn, bk, interpret,
     n_k = k // bk
     grid = (b // bm, n // bn, n_k)
 
+    # Step sidecars travel chunk-major with a unit dim (xs [B, K/bk] as
+    # [K/bk, B, 1], ws [K/bk, N] as [K/bk, 1, N], epilogue steps as
+    # [N/bq, B, 1]): Mosaic needs a block's last two dims divisible by
+    # (8, 128) or equal to the array's, which a (bm, 1) or (1, bn) block
+    # of the [B, K/bk] / [K/bk, N] layouts is not.
+    if x_pq:
+        x_ops = (x_ops[0], x_ops[1].T[:, :, None])
+    if w_pq:
+        w_ops = (w_ops[0], w_ops[1][:, None, :])
     in_specs = [pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk))]
     if x_pq:
-        in_specs.append(pl.BlockSpec((bm, 1), lambda i, j, kk: (i, kk)))
+        in_specs.append(pl.BlockSpec((pl.Squeezed(), bm, 1),
+                                     lambda i, j, kk: (kk, i, 0)))
     in_specs.append(pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)))
     if w_pq:
-        in_specs.append(pl.BlockSpec((1, bn), lambda i, j, kk: (kk, j)))
+        in_specs.append(pl.BlockSpec((pl.Squeezed(), 1, bn),
+                                     lambda i, j, kk: (kk, 0, j)))
 
     if out_q is None:
         out_specs = pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j))
@@ -318,11 +330,11 @@ def _matmul_call(x_ops, w_ops, *, b, k, n, l_i, l_w, bm, bn, bk, interpret,
         bq = out_q[1]
         out_specs = [
             pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-            pl.BlockSpec((bm, bn // bq), lambda i, j, kk: (i, j)),
+            pl.BlockSpec((bn // bq, bm, 1), lambda i, j, kk: (j, i, 0)),
         ]
         out_shape = [
             jax.ShapeDtypeStruct((b, n), jnp.int8),
-            jax.ShapeDtypeStruct((b, n // bq), jnp.float32),
+            jax.ShapeDtypeStruct((n // bq, b, 1), jnp.float32),
         ]
 
     scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
@@ -337,7 +349,7 @@ def _matmul_call(x_ops, w_ops, *, b, k, n, l_i, l_w, bm, bn, bk, interpret,
     kernel = _make_matmul_kernel(l_i=l_i, l_w=l_w, n_k=n_k, x_pq=x_pq,
                                  w_pq=w_pq, mode=mode, pipeline=pipeline,
                                  out_q=out_q)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
@@ -346,6 +358,10 @@ def _matmul_call(x_ops, w_ops, *, b, k, n, l_i, l_w, bm, bn, bk, interpret,
         scratch_shapes=scratch,
         interpret=interpret,
     )(*x_ops, *w_ops)
+    if out_q is None:
+        return out
+    m, s = out
+    return m, s[:, :, 0].T
 
 
 def _out_q(out_bits, out_block, bn):

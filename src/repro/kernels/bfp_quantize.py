@@ -47,17 +47,21 @@ def bfp_quantize_pallas(x: jax.Array, *, bits: int = 8, bm: int = 256,
         raise ValueError(f"shape {x.shape} not a multiple of ({bm},{bk})")
     grid = (m_rows // bm, k // bk)
     kernel = functools.partial(_bfp_quantize_kernel, bits=bits)
-    return pl.pallas_call(
+    # exponents leave the kernel block-major [K/bk, M, 1]: a (bm, 1) block
+    # of [M, K/bk] breaks Mosaic's rule that a block's last two dims
+    # divide by (8, 128) or equal the array's
+    m, e = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((bm, bk), lambda i, j: (i, j))],
         out_specs=[
             pl.BlockSpec((bm, bk), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, 1), lambda i, j: (i, j)),
+            pl.BlockSpec((pl.Squeezed(), bm, 1), lambda i, j: (j, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m_rows, k), jnp.int8),
-            jax.ShapeDtypeStruct((m_rows, k // bk), jnp.int32),
+            jax.ShapeDtypeStruct((k // bk, m_rows, 1), jnp.int32),
         ],
         interpret=interpret,
     )(x)
+    return m, e[:, :, 0].T

@@ -1,8 +1,9 @@
 """Public jit'd wrappers around the Pallas BFP kernels.
 
-Handles shape padding to tile multiples, CPU-interpret dispatch (this
-container has no TPU; ``interpret=True`` runs the kernel body in Python),
-tile selection (autotune cache -> fallback table), and policy plumbing.
+Handles shape padding to tile multiples, interpret dispatch
+(:func:`default_interpret`: the kernel body runs in the Pallas
+interpreter on the CPU backend and compiles everywhere else), tile
+selection (autotune cache -> fallback table), and policy plumbing.
 The contract is identical to the emulated path in ``repro.core.bfp_dot``
 with Scheme.TILED and ``block_k == bk`` — tests assert all three
 (kernel, ref oracle, core library) agree.  Model code reaches these
@@ -54,8 +55,11 @@ __all__ = ["bfp_matmul", "bfp_matmul_prequant", "bfp_conv2d",
 ActOrArray = Union[jax.Array, dict]
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def default_interpret() -> bool:
+    """Whether kernels run in the Pallas interpreter: only on the CPU
+    backend (the test suite's case).  Anywhere else they compile, and a
+    backend Mosaic cannot target fails loudly instead of falling back."""
+    return jax.default_backend() == "cpu"
 
 
 def _pad_to(x: jax.Array, mult: Tuple[int, ...],
@@ -142,7 +146,7 @@ def bfp_matmul(x2d: ActOrArray, w: jax.Array, policy: BFPPolicy,
     unpipelined datapath; every combination is bit-identical).
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = default_interpret()
     x_pq = is_prequant(x2d)
     if x_pq:
         b, k = x2d["m"].shape
@@ -190,7 +194,7 @@ def bfp_matmul_prequant(x2d: ActOrArray, wm: jax.Array, ws: jax.Array,
     ``x2d`` may be an activation-prequant dict with the SAME block size.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = default_interpret()
     x_pq = is_prequant(x2d)
     b, k = (x2d["m"] if x_pq else x2d).shape
     n = wm.shape[1]
@@ -320,7 +324,7 @@ def bfp_conv2d(x: ActOrArray, w_hwio: jax.Array, policy: BFPPolicy,
     ``out_policy`` requests the epilogue-requantized {"m","s"} output.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = default_interpret()
     x_pq = is_prequant(x)
     b, h, w_in, c = (x["m"] if x_pq else x).shape
     kh, kw, c2, oc = w_hwio.shape
@@ -370,7 +374,7 @@ def bfp_conv2d_prequant(x: ActOrArray, wm_hwio: jax.Array, ws: jax.Array,
     ``bk | C``) — the fully-prequantized conv->conv chain.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = default_interpret()
     x_pq = is_prequant(x)
     b, h, w_in, c = (x["m"] if x_pq else x).shape
     kh, kw, c2, oc = wm_hwio.shape
@@ -413,7 +417,7 @@ def bfp_quantize(x: jax.Array, bits: int, block_k: int,
                  interpret: Optional[bool] = None):
     """[M,K] -> (mantissa int8 [M,K], exps int32 [M,ceil(K/bk)]) padded-safe."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = default_interpret()
     m_rows, k = x.shape
     # same aligned floor as default_tiles (one helper, one rationale);
     # the streaming quantizer has no MXU operand so it rides a taller

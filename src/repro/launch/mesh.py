@@ -9,10 +9,15 @@ Single pod:  (16, 16)      axes ("data", "model")   — 256 chips (v5e pod)
 Multi pod:   (2, 16, 16)   axes ("pod", "data", "model") — 512 chips.
 The "pod" axis carries pure data parallelism; gradient reduction across it
 is the slow-link collective the multi-pod dry-run proves out.
+
+Every axis is ``AxisType.Auto``: ``dist.sharding.shard`` annotates with
+``with_sharding_constraint``, which the Explicit axes that
+``jax.make_mesh`` builds by default on JAX 0.9 reject.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_mesh"]
 
@@ -20,9 +25,10 @@ __all__ = ["make_production_mesh", "make_mesh"]
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests use small ones, e.g. (2, 2))."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))

@@ -15,6 +15,7 @@ from repro.configs.base import reduced
 from repro.configs.registry import ARCHS
 from repro.core.policy import BFPPolicy, PAPER_DEFAULT
 from repro.core.prequant import quantize_param_tree
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.lm.model import init_params
 from repro.serve.engine import Request, ServeEngine
 
@@ -40,6 +41,7 @@ def main():
                     help="prompt tokens a prefilling slot consumes per "
                          "step in continuous mode (0 = whole prompt)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     base = ARCHS[args.arch]
     cfg = base if args.scale == "full" else reduced(

@@ -11,16 +11,23 @@ or as several MULTI-TENANT models in one process:
       --scale full --mesh 1x1 --bfp --strict-backend
   PYTHONPATH=src python -m repro.launch.serve_cnn \
       --tenants lenet,cifarnet --requests 12 --bfp
+
+``--bfp`` is the paper's EQ4 policy, which the ``emulated`` (pure jnp)
+backend executes: the Pallas kernels need Scheme.TILED, so this launcher
+never runs them.  ``chip_smoke.py`` drives the kernel path.  The exit
+code is 1 when any request failed.
 """
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 import jax
 
 from repro.core.policy import PAPER_DEFAULT
 from repro.dist.sharding import DEFAULT_RULES
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models.cnn import MODELS
 from repro.serve.cnn import CnnServeEngine, ImageRequest
@@ -60,9 +67,21 @@ def _serve_tenants(args, policy):
     print(f"{st['total']['completed']} requests across {len(names)} "
           f"tenants in {dt:.2f}s ({st['total']['completed'] / dt:.1f} "
           f"req/s) batching={args.batching}")
+    return _report_failures([r for _, r in reqs])
 
 
-def main():
+def _report_failures(reqs) -> int:
+    """Exit code for a finished run: 1 (with the first error on stderr)
+    when any request ended without logits, else 0."""
+    failed = [r for r in reqs if r.error is not None or not r.done]
+    if not failed:
+        return 0
+    print(f"{len(failed)} of {len(reqs)} requests failed; first: "
+          f"{failed[0].error!r}", file=sys.stderr)
+    return 1
+
+
+def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", choices=sorted(MODELS),
                     help="single-tenant model (or use --tenants)")
@@ -88,13 +107,13 @@ def main():
     ap.add_argument("--max-wait", type=int, default=4,
                     help="bucket mode: deferred steps before a partial "
                          "batch runs anyway")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     policy_ = (PAPER_DEFAULT.with_(straight_through=False) if args.bfp
                else None)
     if args.tenants:
-        _serve_tenants(args, policy_)
-        return
+        return _serve_tenants(args, policy_)
     if not args.model:
         ap.error("pass --model (single tenant) or --tenants")
 
@@ -131,13 +150,14 @@ def main():
     t0 = time.perf_counter()
     eng.run()
     dt = max(time.perf_counter() - t0, 1e-9)
-    served = [r for r in reqs if r.done]
+    served = [r for r in reqs if r.done and r.error is None]
     for r in served[:4]:
         print(f"req {r.rid}: label={r.label}")
-    print(f"{len(served)} requests in {dt:.2f}s "
+    print(f"{len(served)} requests served in {dt:.2f}s "
           f"({len(served) / dt:.1f} req/s) model={args.model} "
           f"bfp={args.bfp} prequant={args.prequant} mesh={args.mesh}")
+    return _report_failures(reqs)
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

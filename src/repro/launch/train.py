@@ -20,6 +20,7 @@ from repro.configs.registry import ARCHS
 from repro.core.policy import PAPER_DEFAULT
 from repro.data.pipeline import LMBatchSpec
 from repro.dist.compress import make_compressor
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import optimizers as opt
 from repro.train.loop import LoopConfig, run_training
 from repro.train.step import init_state, make_train_step
@@ -41,6 +42,7 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
     args = ap.parse_args()
+    enable_compile_cache()
 
     base = ARCHS[args.arch]
     if args.scale == "full":

@@ -25,9 +25,10 @@ the LM decode engine has:
     traced forward per bucket shape;
   * data-parallel batch sharding through ``dist.sharding.axis_rules``
     + a ``launch.mesh`` mesh: the stacked batch is annotated
-    ``("batch", None, None, None)`` before the forward, so the SAME
-    code path runs 1-device in tier-1 tests (identity / trivial mesh)
-    and N-device in production.
+    ``("batch", None, None, None)`` and the forward runs under
+    ``jax.shard_map`` (Pallas kernels cannot be partitioned
+    automatically), so every device runs the kernels on its own slice
+    of the batch; the same code runs 1-device in tier-1 tests.
 
 Bit-exactness contract (pinned by tests/test_serve_cnn.py through
 ``engine.taps`` events): a request served through the engine produces
@@ -49,7 +50,7 @@ from repro import engine as EG
 from repro.dist import sharding as DS
 from repro.engine import PolicyLike
 from repro.engine.backends import BackendUnsupportedError
-from repro.engine.plan import Plan
+from repro.engine.plan import BoundForward, Plan
 from repro.serve.degrade import (DeadlineExceeded, DegradeConfig,
                                  DegradeController, QueueOverloaded,
                                  float_params)
@@ -125,7 +126,9 @@ class CnnServeEngine:
         the plan carries no downgraded (fallback) sites.
       mesh / rules: optional ``launch.mesh`` mesh + logical-axis rules
         (default ``dist.sharding.DEFAULT_RULES``); when given, every
-        forward runs under ``axis_rules`` with the batch axis sharded.
+        forward runs under ``jax.shard_map`` with the batch split over
+        the mesh axis the ``"batch"`` rule names (``Plan.jit_forward``),
+        and buckets round up to multiples of that axis's size.
       jit: jit the bound forward (shared across engines via
         ``Plan.jit_forward``).  ``jit=False`` runs eagerly — slower,
         but ``engine.taps`` observers see every GEMM/conv site (taps
@@ -205,6 +208,14 @@ class CnnServeEngine:
         self.mesh = mesh
         self.rules = dict(rules) if rules is not None \
             else dict(DS.DEFAULT_RULES)
+        ax = self.rules.get("batch") if mesh is not None else None
+        self._batch_axis = tuple(ax) if isinstance(ax, list) else ax
+        if self._batch_axis is not None:
+            # shard_map splits each batch evenly over the batch axis:
+            # round every bucket up to a multiple of its size
+            d = DS.axis_size(mesh.shape, self._batch_axis)
+            self.buckets = tuple(sorted({-(-b // d) * d
+                                         for b in self.buckets}))
         self._jit = jit
         self._fwd = self._make_fwd(self.plan)
         self._shape: Optional[Tuple[int, ...]] = None
@@ -251,9 +262,12 @@ class CnnServeEngine:
         self.ncalls = 0
 
     def _make_fwd(self, plan: Plan) -> Callable[..., Any]:
-        if self._jit:
+        if not self._jit:
+            return lambda x: self.apply_fn(plan.params, x, plan)
+        if self._batch_axis is None:
             return plan.jit_forward(self.apply_fn)
-        return lambda x: self.apply_fn(plan.params, x, plan)
+        return plan.jit_forward(self.apply_fn, mesh=self.mesh,
+                                batch_axis=self._batch_axis)
 
     # -- admission ----------------------------------------------------------
 
@@ -312,10 +326,8 @@ class CnnServeEngine:
             tree = float_params(plan.params)
             fn = self.apply_fn
 
-            def eager(x, _t=tree):
-                return fn(_t, x, None)
-
-            fwd = jax.jit(eager) if self._jit else eager
+            fwd = BoundForward(lambda t, x: fn(t, x, None), tree,
+                               jit=self._jit)
             self._float_fwds[degraded] = fwd
         return fwd
 

@@ -107,7 +107,7 @@ def tune_gemm(b: int, k: int, n: int, policy, *, cache: TuneCache,
     from repro.kernels import ops  # late: ops imports tune.tables
 
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = ops.default_interpret()
     target = TuneCache.target(interpret)
     ent = cache.lookup("gemm", b, k, n, policy.l_i, policy.l_w,
                        policy.block_k, target)
@@ -158,7 +158,7 @@ def tune_conv(b: int, h: int, w_in: int, c: int, kh: int, oc: int,
     from repro.kernels import ops  # late: ops imports tune.tables
 
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = ops.default_interpret()
     target = TuneCache.target(interpret)
     kk = kh * kh * c
     oh, ow, _, _ = conv_geometry(h, w_in, kh, kh, stride, padding)

@@ -3,11 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # minimal container: deterministic fallback sampler
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import bfp
 from repro.core.bfp import Rounding, Scheme

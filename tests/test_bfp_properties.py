@@ -1,8 +1,7 @@
 """Hypothesis property suite: core BFP invariants + the paper's NSR bound.
 
 Replaces ad-hoc point checks with generated cases (ISSUE 4): every
-property runs 200+ examples (real hypothesis when installed; the
-deterministic ``_hypothesis_stub`` honors ``max_examples`` otherwise).
+property runs 200+ examples.
 
 Invariants pinned here are exactly what the CNN serving stack relies on:
 
@@ -20,11 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # minimal container: deterministic fallback sampler
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import bfp, nsr, packed, prequant
 from repro.core.bfp import Rounding, Scheme

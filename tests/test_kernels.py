@@ -7,11 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # minimal container: deterministic fallback sampler
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import BFPPolicy, Scheme
 from repro.core.bfp_dot import bfp_matmul_2d

@@ -7,11 +7,7 @@ assert much tighter bounds on synthetic data.
 import jax
 import jax.numpy as jnp
 import pytest
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # minimal container: deterministic fallback sampler
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import nsr
 from repro.core.policy import BFPPolicy

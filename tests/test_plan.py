@@ -130,6 +130,36 @@ def test_plan_jit_closure_safe():
         np.asarray(small.lenet_apply(plan.params, x, plan)))
 
 
+def test_jit_forward_passes_params_as_arguments():
+    """Plan.jit_forward hands the plan's arrays to the compiled program
+    as arguments: bit-identical to jitting a closure over them, but the
+    lowered program embeds no weight constant (a closure would put the
+    whole model into every bucket's program and its cache key)."""
+    params = small.lenet_init(KEY)
+    x = jax.random.normal(KEY, (2, 28, 28, 1))
+    plan = EG.bind(params, EQ4)
+    fwd = plan.jit_forward(small.lenet_apply)
+    closure = jax.jit(lambda xx: small.lenet_apply(plan.params, xx, plan))
+    np.testing.assert_array_equal(np.asarray(fwd(x)),
+                                  np.asarray(closure(x)))
+    lowered = fwd.lower(x)
+    n_arrays = len(jax.tree_util.tree_leaves(plan.params))
+    assert len(jax.tree_util.tree_leaves(lowered.args_info)) == n_arrays + 1
+    assert 'dense<"0x' in closure.lower(x).as_text()   # embedded weights
+    assert 'dense<"0x' not in lowered.as_text()
+
+
+def test_jit_forward_refuses_batch_shared_exponent_on_mesh():
+    """Under shard_map each device quantizes only its slice of the batch:
+    exact for row-local blocks (TILED), but an EQ4 activation exponent
+    spans the whole batch, so a multi-device batch axis is refused."""
+    from jax.sharding import AbstractMesh
+    mesh = AbstractMesh((4, 1), ("data", "model"))
+    plan = EG.bind(small.lenet_init(KEY), EQ4)
+    with pytest.raises(ValueError, match="EQ2/EQ4"):
+        plan.jit_forward(small.lenet_apply, mesh=mesh)
+
+
 def test_plan_model_paths_restricts_and_extends():
     params = small.lenet_init(KEY)
     plan = EG.bind(params, EQ4, model_paths=["c1", ("extra/site", "gemm")])

@@ -16,6 +16,8 @@ Key contracts:
   * continuous batching: more requests than slots drain fully, slots
     are reused.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -257,6 +259,26 @@ def test_googlenet_multi_head_serves_main_logits():
     eng.run()
     direct = googlenet.apply(eng.plan.params, x[None], eng.plan)[0]
     np.testing.assert_array_equal(r.logits, np.asarray(direct[0]))
+
+
+def test_launcher_exits_nonzero_when_forward_raises(monkeypatch, capsys):
+    """The engine completes a raising forward's requests with ``error``
+    set (slots must not leak); the launcher must count those as failed,
+    report 0 served, and exit non-zero."""
+    from repro.launch import serve_cnn
+
+    def boom(params, x, policy):
+        raise RuntimeError("forward failed")
+
+    lenet = dataclasses.replace(MODELS["lenet"], apply=boom)
+    monkeypatch.setattr(serve_cnn, "MODELS", {"lenet": lenet})
+    monkeypatch.setattr(serve_cnn, "enable_compile_cache", lambda: None)
+    rc = serve_cnn.main(["--model", "lenet", "--requests", "3",
+                         "--slots", "2"])
+    out, err = capsys.readouterr()
+    assert rc == 1
+    assert "0 requests served" in out
+    assert "3 of 3 requests failed" in err and "forward failed" in err
 
 
 def test_vgg_reduced_through_engine():
