@@ -25,7 +25,9 @@ What is resolved when:
     (downgrades warn once, or raise with ``strict=True``), weight
     pre-quantization;
   * call time: only geometry-dependent conv fusion (stride/padding) and
-    the backend execution itself.
+    the backend execution itself, under ``jax.named_scope(<site path>)``
+    so every kernel a compiled program launches names its site in its
+    ``op_name`` (``jit(run)/layer1/0/c1/jit(bfp_conv2d_...)/pallas_call``).
 
 Paths the walk cannot see (e.g. the MoE expert runtime path "moe" vs
 its per-matrix tree leaves "moe/w1...") fall back to legacy per-call
@@ -218,11 +220,12 @@ class Plan:
         gv = _grad_vjp()
         with self._tuned():
             if site is not None and site.kind == "gemm":
-                if gv.routable(x, w, key, out_policy) and w.ndim == 2:
-                    return gv.gemm_bound(x, w, site)
-                return gemm_and_tap(x, w, site.policy, key,
-                                    backend=site.backend, path=path,
-                                    out_policy=out_policy)
+                with jax.named_scope(path):
+                    if gv.routable(x, w, key, out_policy) and w.ndim == 2:
+                        return gv.gemm_bound(x, w, site)
+                    return gemm_and_tap(x, w, site.policy, key,
+                                        backend=site.backend, path=path,
+                                        out_policy=out_policy)
             # unbound path: legacy per-call resolution (strict kept)
             if gv.routable(x, w, key, out_policy) and w.ndim == 2:
                 return gv.gemm(x, w, self.policy, path, self.strict)
@@ -239,11 +242,12 @@ class Plan:
                   and padding in ("SAME", "VALID"))
         with self._tuned():
             if site is not None and site.kind == "conv":
-                if routed:
-                    return gv.conv2d_bound(x, w, site, stride, padding)
-                return conv_and_tap(x, w, site.policy, stride, padding,
-                                    key, backend=site.backend, path=path,
-                                    out_policy=out_policy)
+                with jax.named_scope(path):
+                    if routed:
+                        return gv.conv2d_bound(x, w, site, stride, padding)
+                    return conv_and_tap(x, w, site.policy, stride,
+                                        padding, key, backend=site.backend,
+                                        path=path, out_policy=out_policy)
             if routed:
                 return gv.conv2d(x, w, self.policy, stride, padding,
                                  path, self.strict)

@@ -14,7 +14,10 @@ or as several MULTI-TENANT models in one process:
 
 ``--bfp`` is the paper's EQ4 policy, which the ``emulated`` (pure jnp)
 backend executes: the Pallas kernels need Scheme.TILED, so this launcher
-never runs them.  ``chip_smoke.py`` drives the kernel path.  The exit
+never runs them.  ``chip_smoke.py`` drives the kernel path.  The last
+line before the exit gives the host time per forward of each phase of
+the serving step, the mean queue wait and the share of bucket rows that
+held a request, from the engine's counters (docs/tracing.md).  The exit
 code is 1 when any request failed.
 """
 from __future__ import annotations
@@ -31,6 +34,7 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models.cnn import MODELS
 from repro.serve.cnn import CnnServeEngine, ImageRequest
+from repro.serve.spans import PREFIX
 
 
 def _serve_tenants(args, policy):
@@ -67,7 +71,23 @@ def _serve_tenants(args, policy):
     print(f"{st['total']['completed']} requests across {len(names)} "
           f"tenants in {dt:.2f}s ({st['total']['completed'] / dt:.1f} "
           f"req/s) batching={args.batching}")
+    print(phase_line(st["total"]))
     return _report_failures([r for _, r in reqs])
+
+
+def phase_line(stats) -> str:
+    """One line from a serve engine's ``stats``: host ms per forward of
+    each ``serve.*`` phase, queue wait per admitted request, and the
+    share of bucket rows that held a request."""
+    n = stats.get("forwards", 0)
+    if not n:
+        return "no forwards"
+    phases = " ".join(f"{k[len(PREFIX + 'serve.'):]} {v / n * 1e-6:.3f}"
+                      for k, v in stats.items() if k.startswith(PREFIX))
+    wait = stats["queue_wait_ns"] / max(stats["admitted"], 1) * 1e-6
+    return (f"host ms per forward ({n} forwards): {phases}; queue wait "
+            f"{wait:.3f} ms per request; rows filled "
+            f"{100 * stats['rows_live'] / stats['rows']:.1f}%")
 
 
 def _report_failures(reqs) -> int:
@@ -156,6 +176,7 @@ def main(argv=None) -> int:
     print(f"{len(served)} requests served in {dt:.2f}s "
           f"({len(served) / dt:.1f} req/s) model={args.model} "
           f"bfp={args.bfp} prequant={args.prequant} mesh={args.mesh}")
+    print(phase_line(eng.stats))
     return _report_failures(reqs)
 
 

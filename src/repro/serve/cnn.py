@@ -51,10 +51,12 @@ from repro.dist import sharding as DS
 from repro.engine import PolicyLike
 from repro.engine.backends import BackendUnsupportedError
 from repro.engine.plan import BoundForward, Plan
+from repro.models.cnn import head_logits
 from repro.serve.degrade import (DeadlineExceeded, DegradeConfig,
                                  DegradeController, QueueOverloaded,
                                  float_params)
 from repro.serve.slots import SlotTable
+from repro.serve.spans import SpanRecorder
 
 __all__ = ["ImageRequest", "CnnServeEngine", "default_buckets"]
 
@@ -83,6 +85,8 @@ class ImageRequest:
     deadline: Optional[float] = None
     error: Optional[BaseException] = None
     degraded: bool = False
+    #: ``time.perf_counter_ns`` at ``submit`` (the queue wait's start)
+    submitted_ns: int = 0
 
 
 def default_buckets(slots: int) -> Tuple[int, ...]:
@@ -251,15 +255,28 @@ class CnnServeEngine:
         #: serving counters — the shared taxonomy (DESIGN.md §9): every
         #: request ends in exactly one of completed/expired/failed
         #: (shed requests were never enqueued); float_retries and
-        #: degraded_served tag HOW completions were served
+        #: degraded_served tag HOW completions were served.  ``forwards``
+        #: counts batched forwards issued (retries included), ``rows`` /
+        #: ``rows_live`` their bucket rows and the live ones among them,
+        #: ``admitted`` the requests given a slot and ``queue_wait_ns``
+        #: their time from submit to admission, and ``host_ns.<phase>``
+        #: the host time of each phase of :meth:`step` (``self.spans``,
+        #: docs/tracing.md)
         self.stats: Dict[str, int] = {"shed": 0, "expired": 0,
                                       "failed": 0, "completed": 0,
                                       "float_retries": 0,
-                                      "degraded_served": 0}
-        #: total batched forwards issued (retries included) — the load
-        #: harness's machine-independent virtual-time unit
-        #: (serve.load ``call_cost``)
-        self.ncalls = 0
+                                      "degraded_served": 0,
+                                      "forwards": 0, "rows": 0,
+                                      "rows_live": 0, "admitted": 0,
+                                      "queue_wait_ns": 0}
+        self.spans = SpanRecorder(self.stats)
+
+    @property
+    def ncalls(self) -> int:
+        """Total batched forwards issued (retries included) — the load
+        harness's machine-independent virtual-time unit (serve.load
+        ``call_cost``)."""
+        return self.stats["forwards"]
 
     def _make_fwd(self, plan: Plan) -> Callable[..., Any]:
         if not self._jit:
@@ -302,6 +319,7 @@ class CnnServeEngine:
             raise ValueError(f"image shape {tuple(img.shape)} != engine "
                              f"shape {self._shape} (slot table is "
                              f"shape-stable)")
+        req.submitted_ns = time.perf_counter_ns()
         self.table.submit(req)
         return req
 
@@ -367,9 +385,9 @@ class CnnServeEngine:
             r.done = True
             self.stats["expired"] += 1
 
-    def _run_group(self, group: List[int], degraded: bool = False) -> None:
-        reqs = [self.table.req[s] for s in group]
-        bucket = self._bucket_for(len(reqs))
+    def _batch_in(self, reqs: List[ImageRequest], bucket: int) -> jax.Array:
+        """The group's images as the sharded device batch of ``bucket``
+        rows."""
         imgs = [r.image for r in reqs]
         if len(imgs) < bucket:
             # pad with a DUPLICATE of a live image: rows are processed
@@ -381,28 +399,22 @@ class CnnServeEngine:
             # shifts; a trained model's bias pattern could otherwise own
             # an EQ2/EQ4 whole-matrix exponent from layer 2 on.)
             imgs = imgs + [imgs[0]] * (bucket - len(imgs))
-        try:
-            x = jnp.stack(imgs)
-            self.ncalls += 1
-            with self._sharding_ctx():
-                x = DS.shard(x, *_BATCH_AXES)
-                out = (self._fb_fwd if degraded else self._fwd)(x)
-            logits = out[0] if isinstance(out, (tuple, list)) else out
-            logits = np.asarray(logits)
-            if self._float_retry and \
-                    not np.all(np.isfinite(logits[:len(reqs)])):
-                # one retry on the float reference of the SAME weights:
-                # isolates a blown-up BFP datapath (exponent SEU, bad
-                # container) from a genuinely divergent model
-                self.stats["float_retries"] += 1
-                self.ncalls += 1
-                with self._sharding_ctx():
-                    out = self._float_fwd(degraded)(x)
-                logits = out[0] if isinstance(out, (tuple, list)) else out
-                logits = np.asarray(logits)
-        except Exception as e:                    # noqa: BLE001 — slots
-            self._fail_group(group, reqs, e)      # must never leak
-            return
+        x = jnp.stack(imgs)
+        with self._sharding_ctx():
+            return DS.shard(x, *_BATCH_AXES)
+
+    def _dispatch(self, fwd: Callable[..., Any], x: jax.Array,
+                  live: int) -> Any:
+        """Issue one forward of ``x`` (``live`` of its rows are
+        requests); returns before the device finishes."""
+        self.stats["forwards"] += 1
+        self.stats["rows"] += x.shape[0]
+        self.stats["rows_live"] += live
+        with self._sharding_ctx():
+            return fwd(x)
+
+    def _complete(self, group: List[int], reqs: List[ImageRequest],
+                  logits: np.ndarray, degraded: bool) -> None:
         for i, (s, r) in enumerate(zip(group, reqs)):
             r.logits = logits[i]
             r.label = int(np.argmax(logits[i]))
@@ -412,6 +424,42 @@ class CnnServeEngine:
             if degraded:
                 self.stats["degraded_served"] += 1
             self.table.free(s)
+
+    def _run_group(self, group: List[int], degraded: bool = False) -> None:
+        """One forward of the requests in ``group``'s slots, timed by
+        phase (``serve.batch_in``, ``.dispatch``, ``.logits_wait``,
+        ``.finish``, and ``.float_retry`` when the logits were not
+        finite)."""
+        span = self.spans.span
+        reqs = [self.table.req[s] for s in group]
+        try:
+            with span("serve.batch_in"):
+                x = self._batch_in(reqs, self._bucket_for(len(reqs)))
+            with span("serve.dispatch"):
+                out = self._dispatch(self._fb_fwd if degraded else self._fwd,
+                                     x, len(reqs))
+            with span("serve.logits_wait"):
+                logits = np.asarray(head_logits(out))
+        except Exception as e:                    # noqa: BLE001 — slots
+            self._fail_group(group, reqs, e)      # must never leak
+            return
+        with span("serve.finish"):
+            if not self._float_retry or \
+                    np.all(np.isfinite(logits[:len(reqs)])):
+                self._complete(group, reqs, logits, degraded)
+                return
+        with span("serve.float_retry"):
+            # one retry on the float reference of the SAME weights:
+            # isolates a blown-up BFP datapath (exponent SEU, bad
+            # container) from a genuinely divergent model
+            self.stats["float_retries"] += 1
+            try:
+                logits = np.asarray(head_logits(self._dispatch(
+                    self._float_fwd(degraded), x, len(reqs))))
+            except Exception as e:                # noqa: BLE001
+                self._fail_group(group, reqs, e)
+                return
+            self._complete(group, reqs, logits, degraded)
 
     def step(self) -> int:
         """One engine iteration; returns the number of requests still
@@ -429,28 +477,38 @@ class CnnServeEngine:
         slots are active or ``max_wait`` consecutive deferred steps
         elapse).  While DEGRADED every admission of this step is tagged
         for (and served by) the pre-bound lower-L fallback plan.
+
+        The step runs as one ``serve.step`` of :attr:`spans`; admission
+        and expiry are its ``serve.admit`` phase, then each group's
+        phases follow (:meth:`_run_group`).
         """
-        degraded = False
-        if self.controller is not None:
-            state = self.controller.observe(len(self.table.queue))
-            degraded = (state == DegradeController.DEGRADED and
-                        self._fb_fwd is not None)
-        self._expire()
-        self.table.admit()
-        active = self.table.active()
-        if not active:
+        with self.spans.step():
+            with self.spans.span("serve.admit"):
+                degraded = False
+                if self.controller is not None:
+                    state = self.controller.observe(len(self.table.queue))
+                    degraded = (state == DegradeController.DEGRADED and
+                                self._fb_fwd is not None)
+                self._expire()
+                now = time.perf_counter_ns()
+                for s in self.table.admit():
+                    self.stats["admitted"] += 1
+                    self.stats["queue_wait_ns"] += \
+                        now - self.table.req[s].submitted_ns
+                active = self.table.active()
+            if not active:
+                return self.table.pending()
+            cap = self.buckets[-1]
+            if self.batching == "bucket" and len(active) < cap and \
+                    self._waited < self.max_wait:
+                # bucket barrier: hold the partial batch hoping arrivals
+                # fill it — exactly the p99 stall continuous mode removes
+                self._waited += 1
+                return self.table.pending()
+            self._waited = 0
+            for i in range(0, len(active), cap):
+                self._run_group(active[i:i + cap], degraded=degraded)
             return self.table.pending()
-        cap = self.buckets[-1]
-        if self.batching == "bucket" and len(active) < cap and \
-                self._waited < self.max_wait:
-            # bucket barrier: hold the partial batch hoping arrivals
-            # fill it — exactly the p99 stall continuous mode removes
-            self._waited += 1
-            return self.table.pending()
-        self._waited = 0
-        for i in range(0, len(active), cap):
-            self._run_group(active[i:i + cap], degraded=degraded)
-        return self.table.pending()
 
     def run(self) -> List[Any]:
         """Drain the queue; returns the requests still in flight or

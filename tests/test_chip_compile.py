@@ -4,23 +4,35 @@ Nothing runs here: each test compiles one kernel at a real VGG-16 shape
 (batch 8, 224x224x3 input, 1000 classes) for a v5e that is described,
 not attached, so what the chip's compiler refuses (a block shape that
 breaks the (8, 128) rule, scoped VMEM overflow, a removed Pallas API)
-fails here and costs no chip time.  The topology is described inside a
-fixture, so only the worker that runs this file loads the TPU compiler.
+fails here and costs no chip time.  One more compiles a whole small
+CNN forward, to check the names its kernels carry.  The topology is
+described inside a fixture, so only the worker that runs this file
+loads the TPU compiler.
 """
 import importlib.util
 import os
+import re
+import sys
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import engine
+from repro.core.bfp import Scheme
+from repro.core.policy import BFPPolicy
 from repro.kernels import bfp_conv as CV
 from repro.kernels import bfp_matmul as MM
 from repro.kernels import bfp_quantize as QZ
+from repro.kernels import ops as OPS
 from repro.kernels.ops import _conv_plan
+from repro.models.cnn import MODELS
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+from bench import trace as T  # noqa: E402
 BATCH, BK = 8, 128
 #: vgg16 fc6: [8, 25088] x [25088, 4096]
 FC6_K, FC6_N = 25088, 4096
@@ -117,6 +129,60 @@ def test_xw_prequant_conv5_1(one_chip):
         xm, xs, wm, ws, **kw),
         [(x, jnp.int8), ((*x[:3], c // BK), jnp.float32),
          ((kp, ocp), jnp.int8), ((kp // BK, ocp), jnp.float32)], one_chip)
+
+
+def _zero_params(spec):
+    """``spec``'s reduced parameter tree with zero arrays (a compile needs
+    shapes only; drawing the weights on the CPU would take seconds)."""
+    box = {}
+
+    def arrays(key):
+        p = spec.init(key)
+        box["meta"] = p.pop("meta")
+        return p
+
+    shapes = jax.eval_shape(arrays, jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(lambda s: jnp.zeros(s.shape, s.dtype),
+                                    shapes)
+    params["meta"] = box["meta"]
+    return params
+
+
+def test_cnn_forward_kernels_name_their_sites(one_chip, monkeypatch):
+    """A bound ResNet-50 forward (the reduced widths, 64x64, BFP-8 TILED
+    pallas, prequantized where K allows) compiled for the v5e: every
+    kernel's ``op_name`` carries the path of the plan site that launched
+    it (``Plan`` runs each site under ``jax.named_scope``), the
+    benchmark still classes each kernel as conv or matmul, and the site
+    paths' ``/`` stay out of the HLO instruction names."""
+    spec = MODELS["resnet50"]
+    pol = BFPPolicy(l_w=8, l_i=8, scheme=Scheme.TILED, block_k=128,
+                    backend="pallas", straight_through=False)
+    plan = engine.bind(_zero_params(spec), pol, tree="cnn", strict=True,
+                       prequantize=True)
+    monkeypatch.setattr(OPS, "default_interpret", lambda: False)
+    fwd = plan.jit_forward(spec.apply)
+    arrays = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+              for a in fwd.arrays]
+    x = jax.ShapeDtypeStruct((2, 64, 64, 3), jnp.float32, sharding=one_chip)
+    text = fwd._run.lower(arrays, x).compile().as_text()
+    calls = dict(m.groups() for m in map(T._CALL.match, text.splitlines())
+                 if m)
+    assert len(calls) == len(plan.sites)
+    named = set()
+    for instr, op_name in calls.items():
+        site = re.match(r"jit\(run\)/(.+)/jit\(\w+\)/pallas_call$", op_name)
+        assert site and site.group(1) in plan.sites, op_name
+        named.add(site.group(1))
+    assert named == set(plan.sites)
+    classes = T.kernel_classes([text])["kernels"]
+    assert classes.keys() == calls.keys()
+    assert set(classes.values()) == {"conv", "matmul"}
+    assert any("prequant" in n for n in classes)
+    instrs = re.findall(r"^\s*(?:ROOT )?%?(\S+) = ", text, re.M)
+    assert len(instrs) > len(calls)
+    bad = [n for n in instrs if not re.fullmatch(r"[\w.\-]+", n)]
+    assert not bad, bad[:5]
 
 
 def test_chip_smoke_refuses_without_tpu(capsys):
