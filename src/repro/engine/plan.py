@@ -100,12 +100,21 @@ class BoundForward:
                  jit: bool = True):
         leaves, treedef = jax.tree_util.tree_flatten(params)
         is_arr = [isinstance(l, (jax.Array, np.ndarray)) for l in leaves]
+        #: conv launches per call that take the narrow-channel patch
+        #: path (``kernels.ops.count_patch_convs``), counted when the
+        #: forward was last traced (on every call with ``jit=False``)
+        self.patch_convs = 0
 
         def run(arrays, x, *args):
+            # local: ops imports repro.tune, which imports the engine
+            from repro.kernels.ops import count_patch_convs
             it = iter(arrays)
             tree = treedef.unflatten(
                 [next(it) if a else l for l, a in zip(leaves, is_arr)])
-            return fn(tree, x, *args)
+            with count_patch_convs() as tally:
+                out = fn(tree, x, *args)
+            self.patch_convs = tally["patch"]
+            return out
 
         self.arrays = [l for l, a in zip(leaves, is_arr) if a]
         if mesh is not None:

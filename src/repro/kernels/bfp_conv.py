@@ -46,6 +46,14 @@ program id enters a dynamic slice start.
 Strided columns use the reshape trick: slice [dj : dj + stride*OW] then
 reshape [OW, stride, C] and keep phase 0 — exact for any static stride.
 
+Narrow channels do not reach the kernel as kh x kw convs.  With C = 3
+(ResNet-50's 7x7 stem, VGG-16's conv1_1) every channel slab is a few
+lanes wide and the input plane one value per 128-lane row, so
+``ops.bfp_conv2d`` gathers a float conv whose patch row spans at most
+two K tiles and two 128-lane rows into its patch tensor [B, OH, OW, Kp]
+in XLA, and launches this kernel on it as a 1x1 stride-1 conv: the same
+K tiles in the same order, so the same bits.
+
 Grid: (B, OHp/t_oh, OCp/bn).  The K reduction is an in-kernel static
 loop (n_k tiles), so no cross-step accumulator scratch is needed.  VMEM
 sizing: each program holds the full [Hp, Wp, C] input plane (C padded to

@@ -28,7 +28,10 @@ ISSUE 6 additions, all bit-preserving:
 """
 from __future__ import annotations
 
-from typing import Any, Optional, Tuple, Union
+import collections
+import contextlib
+import contextvars
+from typing import Any, Iterator, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -46,11 +49,12 @@ from repro.kernels.bfp_matmul import (bfp_matmul_pallas,
                                       bfp_matmul_xwprequant_pallas)
 from repro.kernels.bfp_quantize import bfp_quantize_pallas
 from repro.tune import cache as _tune
-from repro.tune.tables import aligned_tile, conv_row_tile, fallback_tiles
+from repro.tune.tables import (MXU_DIM, aligned_tile, conv_row_tile,
+                               fallback_tiles, patch_row_tile)
 
 __all__ = ["bfp_matmul", "bfp_matmul_prequant", "bfp_conv2d",
            "bfp_conv2d_prequant", "bfp_quantize", "default_tiles",
-           "aligned_tile"]
+           "aligned_tile", "count_patch_convs"]
 
 ActOrArray = Union[jax.Array, dict]
 
@@ -307,6 +311,53 @@ def _pad_act_nhwc(x: dict, pads) -> Tuple[jax.Array, jax.Array]:
     return xm, xs
 
 
+def _takes_patch_path(kh: int, kw: int, kp: int, bk: int) -> bool:
+    """Whether a float-input conv runs as a 1x1 conv over its patch
+    tensor: kh*kw > 1 and a zero-padded patch row Kp of at most two K
+    tiles and two 128-lane rows.  Such a conv has so few channels that
+    the implicit kernel holds one per 128-lane row of VMEM, while its
+    patch tensor holds at most two lane rows per output pixel."""
+    return kh * kw > 1 and kp <= 2 * min(bk, MXU_DIM)
+
+
+#: the open :func:`count_patch_convs` tally, if any
+_PATCH_TALLY: contextvars.ContextVar = contextvars.ContextVar(
+    "patch_tally", default=None)
+
+
+@contextlib.contextmanager
+def count_patch_convs() -> Iterator[collections.Counter]:
+    """Count, under the key ``"patch"``, the :func:`bfp_conv2d` launches
+    that take the patch path while the block is open.  Under ``jit`` the
+    count is made while tracing: it is the count of one call."""
+    tally = collections.Counter()
+    token = _PATCH_TALLY.set(tally)
+    try:
+        yield tally
+    finally:
+        _PATCH_TALLY.reset(token)
+
+
+def _conv_patches(x: jax.Array, kh: int, kw: int, stride: int,
+                  padding: str, kp: int) -> jax.Array:
+    """NHWC float32 -> patch tensor [B, OH, OW, Kp]: each output pixel's
+    receptive field in the HWIO-major K order k = (di*kw + dj)*C + c,
+    zero-padded to ``kp``.
+
+    An XLA conv with a one-hot kernel at ``Precision.HIGHEST``: each
+    output takes one product x * 1.0 and adds exact zeros, and HIGHEST
+    carries float32 exactly, so a finite ``x`` is copied bit for bit.
+    On a v5e it ran ~28x faster than strided slices concatenated on the
+    lane axis (3.8 ms against 106 ms for the stem at batch 32, PERF.md
+    §6), which XLA lays out one C = 3 value per 128-lane row."""
+    c = x.shape[3]
+    onehot = jnp.eye(kh * kw * c, kp, dtype=jnp.float32)
+    return jax.lax.conv_general_dilated(
+        x, onehot.reshape(kh, kw, c, kp), (stride, stride), padding,
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST)
+
+
 def bfp_conv2d(x: ActOrArray, w_hwio: jax.Array, policy: BFPPolicy,
                stride: int = 1, padding: str = "SAME",
                interpret: Optional[bool] = None, *,
@@ -322,6 +373,16 @@ def bfp_conv2d(x: ActOrArray, w_hwio: jax.Array, policy: BFPPolicy,
     K zero-pads to a tile multiple exactly like ops.bfp_matmul, so the
     result is bit-identical to im2col + the fused GEMM kernel.
     ``out_policy`` requests the epilogue-requantized {"m","s"} output.
+
+    Narrow channels take the patch path (:func:`_takes_patch_path`:
+    kh*kw > 1 and Kp <= 2 * min(bk, 128), as in ResNet-50's 7x7 stem
+    and VGG-16's conv1_1): a float ``x`` is gathered into its patch
+    tensor [B, OH, OW, Kp] in XLA (named scope ``patches``),
+    which the same kernel then runs as a 1x1 stride-1 conv, with
+    :func:`~repro.tune.tables.patch_row_tile` rows per program unless
+    ``tiles`` or the tune cache say otherwise.  Its K tiles are the
+    implicit kernel's, so the bits are the same.  The rule reads shapes
+    only; :func:`count_patch_convs` counts the launches it takes.
     """
     if interpret is None:
         interpret = default_interpret()
@@ -337,6 +398,22 @@ def bfp_conv2d(x: ActOrArray, w_hwio: jax.Array, policy: BFPPolicy,
         _conv_x_prequant_check(x, c, bk, policy)
     t_oh, bn = _conv_tiles(b * h * w_in, kh * kw * c, oc, policy,
                            interpret, tiles)
+    k = kh * kw * c
+    kp = -(-k // bk) * bk
+    if not x_pq and _takes_patch_path(kh, kw, kp, bk):
+        with jax.named_scope("patches"):
+            x = _conv_patches(x.astype(jnp.float32), kh, kw, stride,
+                              padding, kp)
+        w_hwio = jnp.pad(w_hwio.reshape(1, 1, k, oc),
+                         ((0, 0), (0, 0), (0, kp - k), (0, 0)))
+        b, h, w_in, c = x.shape
+        kh = kw = stride = 1
+        padding = "VALID"
+        if t_oh is None:
+            t_oh = patch_row_tile(h, w_in)
+        tally = _PATCH_TALLY.get()
+        if tally is not None:
+            tally["patch"] += 1
     pads, (oh, ow, ohp, t_oh, bn, kp) = _conv_plan(
         b, h, w_in, c, kh, kw, oc, stride, padding, bk, t_oh, bn)
     fused_q = _conv_epilogue_cfg(out_policy, oc, bn)
@@ -372,6 +449,12 @@ def bfp_conv2d_prequant(x: ActOrArray, wm_hwio: jax.Array, ws: jax.Array,
     :func:`bfp_conv2d` with the same policy.  ``x`` may additionally be
     an activation-prequant dict with the SAME block size (requires
     ``bk | C``) — the fully-prequantized conv->conv chain.
+
+    There is no patch path here: a wire-format K is a ``bk`` multiple,
+    and at bk = 128 a kh*kw > 1 conv with K <= 256 would need
+    kh*kw*C in {128, 256}, which no 3x3, 5x5 or 7x7 conv has, so the
+    narrow convs of the model zoo (C = 1 or 3) all arrive with float
+    weights at :func:`bfp_conv2d`.
     """
     if interpret is None:
         interpret = default_interpret()

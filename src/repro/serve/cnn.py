@@ -258,8 +258,11 @@ class CnnServeEngine:
         #: degraded_served tag HOW completions were served.  ``forwards``
         #: counts batched forwards issued (retries included), ``rows`` /
         #: ``rows_live`` their bucket rows and the live ones among them,
-        #: ``admitted`` the requests given a slot and ``queue_wait_ns``
-        #: their time from submit to admission, and ``host_ns.<phase>``
+        #: ``patch_convs`` their conv launches on the narrow-channel
+        #: patch path (``kernels.ops.bfp_conv2d``; per forward it is
+        #: ``patch_convs / forwards``), ``admitted`` the requests given
+        #: a slot and ``queue_wait_ns`` their time from submit to
+        #: admission, and ``host_ns.<phase>``
         #: the host time of each phase of :meth:`step` (``self.spans``,
         #: docs/tracing.md)
         self.stats: Dict[str, int] = {"shed": 0, "expired": 0,
@@ -267,7 +270,8 @@ class CnnServeEngine:
                                       "float_retries": 0,
                                       "degraded_served": 0,
                                       "forwards": 0, "rows": 0,
-                                      "rows_live": 0, "admitted": 0,
+                                      "rows_live": 0, "patch_convs": 0,
+                                      "admitted": 0,
                                       "queue_wait_ns": 0}
         self.spans = SpanRecorder(self.stats)
 
@@ -280,7 +284,8 @@ class CnnServeEngine:
 
     def _make_fwd(self, plan: Plan) -> Callable[..., Any]:
         if not self._jit:
-            return lambda x: self.apply_fn(plan.params, x, plan)
+            return BoundForward(lambda p, x: self.apply_fn(p, x, plan),
+                                plan.params, jit=False)
         if self._batch_axis is None:
             return plan.jit_forward(self.apply_fn)
         return plan.jit_forward(self.apply_fn, mesh=self.mesh,
@@ -411,7 +416,11 @@ class CnnServeEngine:
         self.stats["rows"] += x.shape[0]
         self.stats["rows_live"] += live
         with self._sharding_ctx():
-            return fwd(x)
+            out = fwd(x)
+        # a forward wrapped by its caller (the benchmark's planted
+        # faults wrap Plan.jit_forward) is no BoundForward and counts none
+        self.stats["patch_convs"] += getattr(fwd, "patch_convs", 0)
+        return out
 
     def _complete(self, group: List[int], reqs: List[ImageRequest],
                   logits: np.ndarray, degraded: bool) -> None:

@@ -18,7 +18,8 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 __all__ = ["aligned_tile", "fallback_tiles", "overflow_cap",
-           "conv_row_tile", "MXU_DIM", "DEEP_K_BK"]
+           "conv_row_tile", "patch_row_tile", "MXU_DIM", "DEEP_K_BK",
+           "PATCH_ROWS"]
 
 #: The MXU systolic array dimension — bm/bn never exceed it by default.
 MXU_DIM = 128
@@ -76,3 +77,19 @@ def conv_row_tile(oh: int, ow: int) -> int:
     per program to feed the MXU a >=128-row M tile when OW is small;
     one row when OW alone is wide enough."""
     return max(1, min(oh, MXU_DIM // max(1, ow)))
+
+
+#: Rows per program, at most, of the pointwise conv a narrow-channel conv
+#: becomes over its patch tensor (``kernels.ops.bfp_conv2d``).  On a v5e
+#: at batch 32 the stem's 1x1 kernel took 3.26 ms at 112 rows a program
+#: and 2.09 ms at 3,136; beyond that the gain is < 5 % and Mosaic's
+#: compile time grows with the tile (PERF.md §6).
+PATCH_ROWS = 4096
+
+
+def patch_row_tile(oh: int, ow: int) -> int:
+    """Default output-row tile of a conv over a patch tensor: the largest
+    divisor of ``oh`` whose rows hold at most :data:`PATCH_ROWS` pixels,
+    so no output row is padded (and no patch row either)."""
+    return max(t for t in range(1, oh + 1)
+               if oh % t == 0 and (t == 1 or t * ow <= PATCH_ROWS))

@@ -122,6 +122,23 @@ def test_conv_full_resolution(one_chip, layer, prequant):
                   [(x, jnp.float32), ((kp, ocp), jnp.float32)], one_chip)
 
 
+@pytest.mark.parametrize("batch,hw,oc", [(32, 224, 64), (2, 32, 8)],
+                         ids=["resnet50_224", "reduced_32"])
+def test_stem_patch_path(one_chip, monkeypatch, batch, hw, oc):
+    """ResNet-50's 7x7/2 stem (C = 3) takes the patch path: an XLA patch
+    tensor and a 1x1 kernel.  At 224² and batch 32 (the serving bucket),
+    and at the reduced widths and 32x32, where the implicit kernel ran
+    out of scoped VMEM (19.05 MB against 17.98 MB)."""
+    monkeypatch.setattr(OPS, "default_interpret", lambda: False)
+    pol = BFPPolicy(l_w=8, l_i=8, scheme=Scheme.TILED, block_k=BK,
+                    backend="pallas", straight_through=False)
+    with OPS.count_patch_convs() as tally:
+        _compiles(lambda x, w: OPS.bfp_conv2d(x, w, pol, 2, "SAME"),
+                  [((batch, hw, hw, 3), jnp.float32),
+                   ((7, 7, 3, oc), jnp.float32)], one_chip)
+    assert tally["patch"] == 1
+
+
 def test_xw_prequant_conv5_1(one_chip):
     x, kp, ocp, kw = _conv_geometry("conv5_1")
     c = x[3]

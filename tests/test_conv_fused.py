@@ -22,6 +22,7 @@ from repro.core.conv_utils import conv_weight_matrix, im2col
 from repro.core.prequant import prequant_conv_leaf
 from repro.engine import PolicyMap
 from repro.kernels import ops, ref
+from repro.kernels.bfp_conv import bfp_conv2d_pallas
 from repro.models.cnn import small
 
 KEY = jax.random.PRNGKey(0)
@@ -275,3 +276,61 @@ def test_conv_epilogue_then_consume_chain():
     y1_f = ops.bfp_conv2d(x, w1, pol1, 1, "SAME", True)
     out_ref = ops.bfp_conv2d(y1_f, w2, pol2, 1, "SAME", True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(out_ref))
+
+
+# ---------------------------------------------------------------------------
+# narrow channels: the patch path (a 1x1 conv over an XLA patch tensor)
+# ---------------------------------------------------------------------------
+
+def _implicit_conv(x, wk, pol, stride, out_policy):
+    """The implicit-im2col kernel launched directly, as ops.bfp_conv2d
+    launched every float conv before the patch path."""
+    b, h, w, c = x.shape
+    kh, kw, _, oc = wk.shape
+    pads, (oh, ow, ohp, t_oh, bn, kp) = ops._conv_plan(
+        b, h, w, c, kh, kw, oc, stride, "SAME", pol.block_k)
+    fused_q = ops._conv_epilogue_cfg(out_policy, oc, bn)
+    ob, obk = fused_q if fused_q is not None else (None, None)
+    out = bfp_conv2d_pallas(
+        jnp.pad(x, pads), ops._pad_to(conv_weight_matrix(wk), (kp, bn)),
+        kh=kh, kw=kw, stride=stride, t_oh=t_oh, ohp=ohp, ow=ow, bn=bn,
+        bk=pol.block_k, interpret=True, out_bits=ob, out_block=obk)
+    return ops._finish_conv(out, oh, oc, out_policy, fused_q)
+
+
+def _as_list(out):
+    return ([out["m"], out["s"]] if EG.is_prequant(out) else [out])
+
+
+@pytest.mark.parametrize("h,w,c,k,stride,epilogue,patch", [
+    (20, 20, 3, 7, 2, False, 1),     # resnet50's stem, even H/W
+    (11, 9, 3, 7, 2, False, 1),      # odd H/W
+    (11, 9, 3, 7, 2, True, 1),
+    (12, 12, 3, 3, 1, False, 1),     # vgg16's conv1_1
+    (7, 9, 3, 3, 1, True, 1),
+    (8, 8, 64, 3, 1, False, 0),      # C = 64, Kp 640: implicit
+    (8, 8, 3, 1, 1, False, 0),       # 1x1: implicit
+], ids=["stem", "stem_odd", "stem_odd_epilogue", "conv1_1",
+        "conv1_1_odd_epilogue", "c64_3x3", "c3_1x1"])
+def test_narrow_conv_patch_path(h, w, c, k, stride, epilogue, patch):
+    """A narrow-channel conv (kh*kw > 1, Kp <= 2 * min(bk, 128)) runs as
+    a 1x1 conv over its XLA patch tensor; the bits are the implicit
+    kernel's and the im2col + bfp_matmul_pallas oracle's, epilogue on or
+    off.  A C = 64 3x3 conv and a 1x1 conv stay implicit, and the
+    counter says which path each took."""
+    x, wk = _case(h, w, c, 16, k, k, seed=h * k + c)
+    pol = _tiled(128)
+    out_pol = _tiled(8) if epilogue else None
+    with ops.count_patch_convs() as tally:
+        out = ops.bfp_conv2d(x, wk, pol, stride, "SAME", interpret=True,
+                             out_policy=out_pol)
+    assert tally["patch"] == patch
+    assert EG.is_prequant(out) == epilogue
+    cols, (b, oh, ow) = im2col(x, k, k, stride, "SAME")
+    gemm = ops.bfp_matmul(cols, conv_weight_matrix(wk), pol,
+                          interpret=True, out_policy=out_pol)
+    gemm = [a.reshape(b, oh, ow, -1) for a in _as_list(gemm)]
+    for want in (_as_list(_implicit_conv(x, wk, pol, stride, out_pol)),
+                 gemm):
+        for got, exp in zip(_as_list(out), want, strict=True):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(exp))

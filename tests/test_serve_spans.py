@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.policy import PALLAS_TILED
 from repro.models.cnn import MODELS
 from repro.serve.cnn import CnnServeEngine, ImageRequest
 from repro.serve.load import Arrival, VirtualClock, run_open_loop
@@ -107,6 +108,25 @@ def test_records_kept_only_on_request(lenet):
     eng.submit(image=imgs[0])
     eng.run()
     assert len(eng.spans.records) == n
+
+
+@pytest.mark.parametrize("jit", [True, False])
+def test_patch_convs_counted_per_forward(lenet, jit):
+    """``patch_convs`` counts each forward's conv launches on the
+    narrow-channel patch path: lenet's c1 (C = 1, 5x5, Kp 128) takes it,
+    c2 (C = 16, Kp 512) does not, and a float forward takes none."""
+    spec, params, imgs = lenet
+    bfp = CnnServeEngine(params, spec.apply,
+                         PALLAS_TILED.with_(straight_through=False),
+                         slots=4, jit=jit)
+    flt = _engine(lenet, jit=jit)
+    for eng in (bfp, flt):
+        for img in imgs[:6]:
+            eng.submit(image=img)
+        eng.run()
+        assert eng.stats["forwards"] == 2
+    assert bfp.stats["patch_convs"] == 2
+    assert flt.stats["patch_convs"] == 0
 
 
 def test_float_retry_is_a_phase(lenet):
