@@ -24,6 +24,7 @@ a single :class:`BFPPolicy` or a per-layer ``repro.engine.PolicyMap``.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional
 
 import jax
@@ -68,11 +69,21 @@ def prequant_leaf(w: jax.Array, policy: BFPPolicy) -> Any:
     """[.., K, N] float -> {"m": int8 [.., K, N], "s": f32 [.., K/bk, N]}."""
     if w.ndim < 2:
         return w
+    k = w.shape[-2]
+    if k % (policy.block_k or k):
+        return w  # odd contraction dim: leave in float
+    return _prequant(w, policy)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _prequant(w: jax.Array, policy: BFPPolicy) -> Any:
+    """:func:`prequant_leaf` of an eligible leaf, as one compiled program
+    per shape and policy: run op by op, a leaf compiled ~20 programs, so
+    a bind on a cold compile cache compiled ~540 for ResNet-50 or
+    GoogLeNet."""
     lead = w.shape[:-2]
     k, n = w.shape[-2:]
     bk = policy.block_k or k
-    if k % bk:
-        return w  # odd contraction dim: leave in float
     w2 = w.reshape(-1, k, n)
 
     def one(mat):
@@ -100,9 +111,16 @@ def prequant_conv_leaf(w_hwio: jax.Array, policy: BFPPolicy) -> Any:
     if w_hwio.ndim != 4:
         return w_hwio
     kh, kw, c, n = w_hwio.shape
-    d = prequant_leaf(w_hwio.reshape(kh * kw * c, n), policy)
-    if not is_prequant(d):
+    if (kh * kw * c) % (policy.block_k or kh * kw * c):
         return w_hwio  # block_k does not divide kh*kw*C
+    return _prequant_conv(w_hwio, policy)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _prequant_conv(w_hwio: jax.Array, policy: BFPPolicy) -> Any:
+    """:func:`_prequant` of the GEMM view, the reshapes in the program."""
+    kh, kw, c, n = w_hwio.shape
+    d = _prequant(w_hwio.reshape(kh * kw * c, n), policy)
     return {"m": d["m"].reshape(kh, kw, c, n), "s": d["s"]}
 
 

@@ -101,19 +101,31 @@ class BoundForward:
         leaves, treedef = jax.tree_util.tree_flatten(params)
         is_arr = [isinstance(l, (jax.Array, np.ndarray)) for l in leaves]
         #: conv launches per call that take the narrow-channel patch
-        #: path (``kernels.ops.count_patch_convs``), counted when the
+        #: path (``kernels.ops.count_conv_launches``), counted when the
         #: forward was last traced (on every call with ``jit=False``)
         self.patch_convs = 0
+        #: MACs the conv launches of one call issue to the MXU, padding
+        #: included (``count_conv_launches``), by the call's batch rows,
+        #: counted when the forward was traced at that batch
+        self.conv_macs_issued: Dict[int, int] = {}
+        shards = 1
+        if mesh is not None:
+            for a in (batch_axis if isinstance(batch_axis, (tuple, list))
+                      else (batch_axis,)):
+                shards *= mesh.shape[a]
 
         def run(arrays, x, *args):
             # local: ops imports repro.tune, which imports the engine
-            from repro.kernels.ops import count_patch_convs
+            from repro.kernels.ops import count_conv_launches
             it = iter(arrays)
             tree = treedef.unflatten(
                 [next(it) if a else l for l, a in zip(leaves, is_arr)])
-            with count_patch_convs() as tally:
+            with count_conv_launches() as tally:
                 out = fn(tree, x, *args)
             self.patch_convs = tally["patch"]
+            # under shard_map this traces one shard's rows
+            self.conv_macs_issued[x.shape[0] * shards] = \
+                tally["macs_issued"] * shards
             return out
 
         self.arrays = [l for l, a in zip(leaves, is_arr) if a]

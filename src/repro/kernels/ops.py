@@ -54,7 +54,7 @@ from repro.tune.tables import (MXU_DIM, aligned_tile, conv_row_tile,
 
 __all__ = ["bfp_matmul", "bfp_matmul_prequant", "bfp_conv2d",
            "bfp_conv2d_prequant", "bfp_quantize", "default_tiles",
-           "aligned_tile", "count_patch_convs"]
+           "aligned_tile", "count_conv_launches"]
 
 ActOrArray = Union[jax.Array, dict]
 
@@ -320,22 +320,37 @@ def _takes_patch_path(kh: int, kw: int, kp: int, bk: int) -> bool:
     return kh * kw > 1 and kp <= 2 * min(bk, MXU_DIM)
 
 
-#: the open :func:`count_patch_convs` tally, if any
-_PATCH_TALLY: contextvars.ContextVar = contextvars.ContextVar(
-    "patch_tally", default=None)
+#: the open :func:`count_conv_launches` tally, if any
+_CONV_TALLY: contextvars.ContextVar = contextvars.ContextVar(
+    "conv_tally", default=None)
 
 
 @contextlib.contextmanager
-def count_patch_convs() -> Iterator[collections.Counter]:
-    """Count, under the key ``"patch"``, the :func:`bfp_conv2d` launches
-    that take the patch path while the block is open.  Under ``jit`` the
-    count is made while tracing: it is the count of one call."""
+def count_conv_launches() -> Iterator[collections.Counter]:
+    """Tally the conv kernel launches made while the block is open:
+    ``"patch"`` counts the :func:`bfp_conv2d` launches that take the
+    patch path, ``"macs_issued"`` the MACs every launch of either conv
+    wrapper issues to the MXU (:func:`_tally_launch`).  Under ``jit`` the
+    tally is made while tracing: it is the tally of one call."""
     tally = collections.Counter()
-    token = _PATCH_TALLY.set(tally)
+    token = _CONV_TALLY.set(tally)
     try:
         yield tally
     finally:
-        _PATCH_TALLY.reset(token)
+        _CONV_TALLY.reset(token)
+
+
+def _tally_launch(b: int, ohp: int, ow: int, kp: int, oc: int,
+                  patch: bool = False) -> None:
+    """Add one conv launch to the open tally: the MACs it issues are
+    ``B * OHp * OW * Kp * ceil(OC / 128) * 128``, padded rows and K
+    included, since a v5e MXU pass is 128 columns wide whatever the
+    output tile ``bn`` is."""
+    tally = _CONV_TALLY.get()
+    if tally is not None:
+        tally["macs_issued"] += b * ohp * ow * kp * (-(-oc // MXU_DIM)
+                                                    * MXU_DIM)
+        tally["patch"] += patch
 
 
 def _conv_patches(x: jax.Array, kh: int, kw: int, stride: int,
@@ -382,7 +397,7 @@ def bfp_conv2d(x: ActOrArray, w_hwio: jax.Array, policy: BFPPolicy,
     :func:`~repro.tune.tables.patch_row_tile` rows per program unless
     ``tiles`` or the tune cache say otherwise.  Its K tiles are the
     implicit kernel's, so the bits are the same.  The rule reads shapes
-    only; :func:`count_patch_convs` counts the launches it takes.
+    only; :func:`count_conv_launches` counts the launches it takes.
     """
     if interpret is None:
         interpret = default_interpret()
@@ -400,7 +415,8 @@ def bfp_conv2d(x: ActOrArray, w_hwio: jax.Array, policy: BFPPolicy,
                            interpret, tiles)
     k = kh * kw * c
     kp = -(-k // bk) * bk
-    if not x_pq and _takes_patch_path(kh, kw, kp, bk):
+    patch = not x_pq and _takes_patch_path(kh, kw, kp, bk)
+    if patch:
         with jax.named_scope("patches"):
             x = _conv_patches(x.astype(jnp.float32), kh, kw, stride,
                               padding, kp)
@@ -411,11 +427,9 @@ def bfp_conv2d(x: ActOrArray, w_hwio: jax.Array, policy: BFPPolicy,
         padding = "VALID"
         if t_oh is None:
             t_oh = patch_row_tile(h, w_in)
-        tally = _PATCH_TALLY.get()
-        if tally is not None:
-            tally["patch"] += 1
     pads, (oh, ow, ohp, t_oh, bn, kp) = _conv_plan(
         b, h, w_in, c, kh, kw, oc, stride, padding, bk, t_oh, bn)
+    _tally_launch(b, ohp, ow, kp, oc, patch)
     fused_q = _conv_epilogue_cfg(out_policy, oc, bn)
     ob, obk = fused_q if fused_q is not None else (None, None)
     w2d = conv_weight_matrix(w_hwio.astype(jnp.float32))
@@ -479,6 +493,7 @@ def bfp_conv2d_prequant(x: ActOrArray, wm_hwio: jax.Array, ws: jax.Array,
     pads, (oh, ow, ohp, t_oh, bn, kp) = _conv_plan(
         b, h, w_in, c, kh, kw, oc, stride, padding, bk, t_oh, bn)
     assert kp == k, "wire-format K is a bk multiple by construction"
+    _tally_launch(b, ohp, ow, kp, oc)
     fused_q = _conv_epilogue_cfg(out_policy, oc, bn)
     ob, obk = fused_q if fused_q is not None else (None, None)
     wm2d = _pad_to(conv_weight_matrix(wm_hwio), (bk, bn))

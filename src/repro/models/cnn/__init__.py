@@ -24,11 +24,12 @@ __all__ = ["CnnSpec", "MODELS", "head_logits"]
 def head_logits(out):
     """Classifier logits from an ``apply()`` output.
 
-    Models with auxiliary heads (GoogLeNet) return a tuple; head 0 is
-    the classifier by :class:`CnnSpec` convention.  Single-head models
-    return the logits array directly.  Training and evaluation code
-    (``repro.train.cnn``, the serve engines) go through this one helper
-    so every registered model trains with the same loss plumbing.
+    An apply with auxiliary heads (``googlenet.apply`` with
+    ``with_aux=True``) returns a tuple whose head 0 is the classifier;
+    the registered applies return the logits array directly.  Training
+    and evaluation code (``repro.train.cnn``, the serve engines) go
+    through this one helper so every registered model trains with the
+    same loss plumbing.
     """
     return out[0] if isinstance(out, tuple) else out
 
@@ -37,8 +38,8 @@ def head_logits(out):
 class CnnSpec:
     """One registered CNN: how to build it and what it eats.
 
-    ``apply(params, x, policy)`` may return a tuple of heads (GoogLeNet's
-    loss3/loss1/loss2) — consumers take head 0 as the classifier output.
+    ``apply(params, x, policy)`` is the served forward and returns the
+    classifier logits (GoogLeNet's computes no aux head).
     """
 
     name: str
@@ -75,9 +76,12 @@ def _resnet50_init(key, *, reduced: bool = True, num_classes: int = 10):
 
 
 def _googlenet_init(key, *, reduced: bool = True, num_classes: int = 10):
+    """The served tree: no aux heads (``googlenet.init(with_aux=True)``
+    builds them for training and the analysis)."""
     if reduced:
-        return _googlenet.init(key, num_classes, width_mult=0.125)
-    return _googlenet.init(key, 1000)
+        return _googlenet.init(key, num_classes, width_mult=0.125,
+                               with_aux=False)
+    return _googlenet.init(key, 1000, with_aux=False)
 
 
 def _lenet_init(key, *, reduced: bool = True, num_classes: int = 10):
@@ -94,8 +98,8 @@ MODELS: Dict[str, CnnSpec] = {
                         224, 32),
     "resnet50": CnnSpec("resnet50", _resnet50_init, _resnet.apply,
                         224, 32),
-    "googlenet": CnnSpec("googlenet", _googlenet_init, _googlenet.apply,
-                         224, 64),
+    "googlenet": CnnSpec("googlenet", _googlenet_init,
+                         _googlenet.serve_apply, 224, 64),
     "lenet": CnnSpec("lenet", _lenet_init, _small.lenet_apply,
                      28, 28, in_ch=1),
     "cifarnet": CnnSpec("cifarnet", _cifarnet_init, _small.cifarnet_apply,

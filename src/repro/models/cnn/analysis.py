@@ -122,8 +122,9 @@ def analyze_model(apply_fn: Callable[[Any, jax.Array, Any], Any],
     engine (every in-repo model does); its return value is ignored —
     the engine taps supply the per-site operands.  ``policy`` is a
     BFPPolicy (uniform) or PolicyMap (sites a rule pins to float are
-    skipped: there is no quantization to analyze there).  Rows appear
-    in execution order.
+    skipped: there is no quantization to analyze there), as are sites
+    whose float input or output is all zero.  Rows appear in execution
+    order.
 
     ``inheritance`` picks the multi-layer model's eta_1 source:
     "analytic" chains predictions in execution order (sequential
@@ -165,6 +166,11 @@ def analyze_model(apply_fn: Callable[[Any, jax.Array, Any], Any],
         if max_sites is not None and len(rows) >= max_sites:
             break
         cols_f, wmat = _site_matrices(f)
+        if not (bool(jnp.any(cols_f != 0)) and bool(jnp.any(f.y != 0))):
+            # no signal (e.g. a branch whose ReLU zeroed its whole input
+            # at a small width): every SNR would read -inf, so the site
+            # has no row
+            continue
         cols_q, _ = _site_matrices(q)
 
         # --- input SNRs: measured + single/multi-layer models -------------

@@ -1,7 +1,12 @@
-"""GoogLeNet (Szegedy et al. 2015) with the three classifier heads the
-paper reports (loss1/loss2/loss3 columns of Table 3).  Inception branch
-convs (1x1 / 3x3 / 5x5, mixed per-branch shapes) all route through
-``engine.conv2d`` — fused implicit-im2col on the pallas backend."""
+"""GoogLeNet (Szegedy et al. 2015, Table 1 and Figure 3) with the two
+local response norms of the published net and, for training and the
+paper's Table 3 analysis, the three classifier heads (loss1/loss2/loss3).
+Inception branch convs (1x1 / 3x3 / 5x5, mixed per-branch shapes) all
+route through ``engine.conv2d``; the norms, the pools and the concats
+run in XLA, each under a ``jax.named_scope`` of its own (``norm1``,
+``inc3a/pool``, ``inc3a/concat``, ...), so the compiled program's
+``op_name`` ties every fusion to its block.  :func:`serve_apply` is the
+served forward: the main head alone, as in a deployment."""
 from __future__ import annotations
 
 
@@ -42,14 +47,17 @@ def _inception_init(key, in_ch, cfg, width_mult):
     }, scale(o1) + scale(o3) + scale(o5) + scale(pp)
 
 
-def _inception(p, x, policy, path=None):
+def _inception(p, x, policy, path):
     cv = lambda name, inp: L.relu(L.conv2d(p[name], inp, 1, "SAME", policy,
                                            path=join_path(path, name)))
     b1 = cv("b1", x)
     b3 = cv("b3", cv("b3r", x))
     b5 = cv("b5", cv("b5r", x))
-    bp = cv("bp", L.max_pool(x, 3, 1, "SAME"))
-    return jnp.concatenate([b1, b3, b5, bp], axis=-1)
+    with jax.named_scope(f"{path}/pool"):
+        pooled = L.max_pool(x, 3, 1, "SAME")
+    bp = cv("bp", pooled)
+    with jax.named_scope(f"{path}/concat"):
+        return jnp.concatenate([b1, b3, b5, bp], axis=-1)
 
 
 def _aux_init(key, in_ch, num_classes, width_mult):
@@ -74,7 +82,9 @@ def _aux(p, x, policy, path=None):
 
 
 def init(key, num_classes: int = 1000, in_ch: int = 3,
-         width_mult: float = 1.0):
+         width_mult: float = 1.0, with_aux: bool = True):
+    """The conv and fc weights; the aux heads (loss1, loss2) only
+    ``with_aux``."""
     scale = lambda c: max(8, int(c * width_mult))
     key, k1, k2, k3 = jax.random.split(key, 4)
     params = {"stem1": L.conv2d_init(k1, in_ch, scale(64), 7, 7),
@@ -87,7 +97,7 @@ def init(key, num_classes: int = 1000, in_ch: int = 3,
         key, sub = jax.random.split(key)
         params[f"inc{cfg[0]}"], ch_out = _inception_init(sub, ch, cfg,
                                                          width_mult)
-        if cfg[0] in _AUX_AFTER:
+        if with_aux and cfg[0] in _AUX_AFTER:
             key, sub = jax.random.split(key)
             params[_AUX_AFTER[cfg[0]]] = _aux_init(sub, ch_out, num_classes,
                                                    width_mult)
@@ -100,31 +110,44 @@ def init(key, num_classes: int = 1000, in_ch: int = 3,
 def apply(params, x: jax.Array, policy: PolicyLike = None,
           with_aux: bool = True):
     """Returns (loss3_logits, loss1_logits, loss2_logits) — the paper's
-    three GoogLeNet columns.  Layer paths: "stem1|stem2r|stem2",
-    "inc<name>/b1|b3r|b3|b5r|b5|bp", "loss1|loss2/conv|fc1|fc2", "fc";
-    ``policy`` is a PolicyLike (incl. a bound ``engine.Plan``)."""
+    three GoogLeNet columns — or, ``with_aux=False``, the loss3 logits
+    alone (the tree then needs no aux heads).  Layer paths:
+    "stem1|stem2r|stem2", "inc<name>/b1|b3r|b3|b5r|b5|bp",
+    "loss1|loss2/conv|fc1|fc2", "fc"; ``policy`` is a PolicyLike (incl. a
+    bound ``engine.Plan``)."""
     x = L.relu(L.conv2d(params["stem1"], x, 2, "SAME", policy,
                         path="stem1"))
-    x = L.max_pool(x, 3, 2, "SAME")
+    with jax.named_scope("pool1"):
+        x = L.max_pool(x, 3, 2, "SAME")
+    with jax.named_scope("norm1"):
+        x = L.local_response_norm(x)
     x = L.relu(L.conv2d(params["stem2r"], x, 1, "SAME", policy,
                         path="stem2r"))
     x = L.relu(L.conv2d(params["stem2"], x, 1, "SAME", policy,
                         path="stem2"))
-    x = L.max_pool(x, 3, 2, "SAME")
-    aux1 = aux2 = None
+    with jax.named_scope("norm2"):
+        x = L.local_response_norm(x)
+    with jax.named_scope("pool2"):
+        x = L.max_pool(x, 3, 2, "SAME")
+    aux = {}
+    pools = iter(("pool3", "pool4"))
     for cfg in _INCEPTION:
         if cfg[0] == "pool":
-            x = L.max_pool(x, 3, 2, "SAME")
+            with jax.named_scope(next(pools)):
+                x = L.max_pool(x, 3, 2, "SAME")
             continue
         x = _inception(params[f"inc{cfg[0]}"], x, policy,
                        path=f"inc{cfg[0]}")
         if with_aux and cfg[0] in _AUX_AFTER:
-            a = _aux(params[_AUX_AFTER[cfg[0]]], x, policy,
-                     path=_AUX_AFTER[cfg[0]])
-            if cfg[0] == "4a":
-                aux1 = a
-            else:
-                aux2 = a
-    x = L.global_avg_pool(x)
+            head = _AUX_AFTER[cfg[0]]
+            aux[head] = _aux(params[head], x, policy, path=head)
+    with jax.named_scope("pool5"):
+        x = L.global_avg_pool(x)
     main = L.dense(params["fc"], x, policy, path="fc")
-    return (main, aux1, aux2) if with_aux else main
+    return (main, aux["loss1"], aux["loss2"]) if with_aux else main
+
+
+def serve_apply(params, x: jax.Array, policy: PolicyLike = None):
+    """The served forward: the loss3 logits, with no aux head built or
+    run (a deployed GoogLeNet, like Caffe's deploy net, has none)."""
+    return apply(params, x, policy, with_aux=False)

@@ -32,7 +32,7 @@ from repro.engine import PolicyLike
 
 __all__ = ["conv2d_init", "conv2d", "im2col", "dense_init", "dense",
            "batchnorm_init", "batchnorm", "max_pool", "avg_pool",
-           "global_avg_pool", "relu"]
+           "global_avg_pool", "local_response_norm", "relu"]
 
 
 def _he_init(key, shape, fan_in):
@@ -125,6 +125,21 @@ def avg_pool(x: jax.Array, window: int, stride: int,
 
 def global_avg_pool(x: jax.Array) -> jax.Array:
     return jnp.mean(x, axis=(1, 2))
+
+
+def local_response_norm(x: jax.Array, size: int = 5, alpha: float = 1e-4,
+                        beta: float = 0.75, k: float = 1.0) -> jax.Array:
+    """Local response normalization across channels, in Caffe's form
+    (GoogLeNet's norm1/norm2): ``x / (k + alpha/size * sum x^2)^beta``,
+    the sum over a window of ``size`` channels centred on each channel,
+    zero beyond the edges.  Float32, in XLA, like ReLU and the pools."""
+    x = x.astype(jnp.float32)
+    half = size // 2
+    sq = jax.lax.reduce_window(
+        x * x, 0.0, jax.lax.add, (1,) * (x.ndim - 1) + (size,),
+        (1,) * x.ndim,
+        ((0, 0),) * (x.ndim - 1) + ((half, size - 1 - half),))
+    return x / (k + alpha / size * sq) ** beta
 
 
 relu = jax.nn.relu

@@ -260,10 +260,12 @@ class CnnServeEngine:
         #: ``rows_live`` their bucket rows and the live ones among them,
         #: ``patch_convs`` their conv launches on the narrow-channel
         #: patch path (``kernels.ops.bfp_conv2d``; per forward it is
-        #: ``patch_convs / forwards``), ``admitted`` the requests given
-        #: a slot and ``queue_wait_ns`` their time from submit to
-        #: admission, and ``host_ns.<phase>``
-        #: the host time of each phase of :meth:`step` (``self.spans``,
+        #: ``patch_convs / forwards``), ``conv_macs_issued`` the MACs
+        #: their conv launches issued to the MXU, padding included
+        #: (``engine.plan.BoundForward.conv_macs_issued``), ``admitted``
+        #: the requests given a slot and ``queue_wait_ns`` their time
+        #: from submit to admission, and ``host_ns.<phase>`` the host
+        #: time of each phase of :meth:`step` (``self.spans``,
         #: docs/tracing.md)
         self.stats: Dict[str, int] = {"shed": 0, "expired": 0,
                                       "failed": 0, "completed": 0,
@@ -271,6 +273,7 @@ class CnnServeEngine:
                                       "degraded_served": 0,
                                       "forwards": 0, "rows": 0,
                                       "rows_live": 0, "patch_convs": 0,
+                                      "conv_macs_issued": 0,
                                       "admitted": 0,
                                       "queue_wait_ns": 0}
         self.spans = SpanRecorder(self.stats)
@@ -420,6 +423,8 @@ class CnnServeEngine:
         # a forward wrapped by its caller (the benchmark's planted
         # faults wrap Plan.jit_forward) is no BoundForward and counts none
         self.stats["patch_convs"] += getattr(fwd, "patch_convs", 0)
+        self.stats["conv_macs_issued"] += getattr(
+            fwd, "conv_macs_issued", {}).get(x.shape[0], 0)
         return out
 
     def _complete(self, group: List[int], reqs: List[ImageRequest],

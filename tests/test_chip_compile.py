@@ -32,6 +32,7 @@ from repro.models.cnn import MODELS
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
+from bench import spec as S  # noqa: E402
 from bench import trace as T  # noqa: E402
 BATCH, BK = 8, 128
 #: vgg16 fc6: [8, 25088] x [25088, 4096]
@@ -132,11 +133,71 @@ def test_stem_patch_path(one_chip, monkeypatch, batch, hw, oc):
     monkeypatch.setattr(OPS, "default_interpret", lambda: False)
     pol = BFPPolicy(l_w=8, l_i=8, scheme=Scheme.TILED, block_k=BK,
                     backend="pallas", straight_through=False)
-    with OPS.count_patch_convs() as tally:
+    with OPS.count_conv_launches() as tally:
         _compiles(lambda x, w: OPS.bfp_conv2d(x, w, pol, 2, "SAME"),
                   [((batch, hw, hw, 3), jnp.float32),
                    ((7, 7, 3, oc), jnp.float32)], one_chip)
     assert tally["patch"] == 1
+
+
+#: (input H == W, C, k, OC, OHp) of GoogLeNet's distinct branch conv
+#: shapes; OHp pads H to conv_row_tile's rows a program (4, 9, 9, 7)
+GOOGLENET_CONVS = {"inc3a/b5": (28, 16, 5, 32, 28),
+                   "inc4a/b5r": (14, 480, 1, 16, 18),
+                   "inc4e/b3": (14, 160, 3, 320, 18),
+                   "inc5b/b1": (7, 832, 1, 384, 7)}
+
+
+@pytest.mark.parametrize("site", sorted(GOOGLENET_CONVS))
+def test_googlenet_branch_conv(one_chip, monkeypatch, site):
+    """GoogLeNet's 5x5 window, narrow output tiles (bn 16-32) and
+    K = 480, 832 and 1440, which no block of 128 divides (the served
+    path keeps such weights in float), at the serving bucket."""
+    hw, c, k, oc, ohp = GOOGLENET_CONVS[site]
+    monkeypatch.setattr(OPS, "default_interpret", lambda: False)
+    pol = BFPPolicy(l_w=8, l_i=8, scheme=Scheme.TILED, block_k=BK,
+                    backend="pallas", straight_through=False)
+    with OPS.count_conv_launches() as tally:
+        _compiles(lambda x, w: OPS.bfp_conv2d(x, w, pol, 1, "SAME"),
+                  [((32, hw, hw, c), jnp.float32),
+                   ((k, k, c, oc), jnp.float32)], one_chip)
+    assert tally["patch"] == 0
+    kp = -(-k * k * c // BK) * BK
+    assert tally["macs_issued"] == 32 * ohp * hw * kp * (-(-oc // 128) * 128)
+
+
+def test_googlenet_forward(one_chip, monkeypatch):
+    """The served GoogLeNet at 224² and bucket 32, bound as the benchmark
+    binds it: one kernel a site (57 convs and the fc, no aux head), the
+    stem alone on the patch path, and the MACs the conv launches issue,
+    B * OHp * OW * Kp * ceil(OC / 128) * 128 summed over the sites
+    (2,829,811,712 an image: row padding, K padded to 128 and OC to the
+    MXU's 128 columns), 1.79x the 1,581,647,872 the convs need."""
+    cfg = S.load_json("configs", "googlenet")
+    ref = S.load_module("configs", "googlenet")
+    shapes = jax.eval_shape(lambda k: ref.init(k, cfg),
+                            jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(lambda s: jnp.zeros(s.shape, s.dtype),
+                                    shapes)
+    pol = BFPPolicy(l_w=8, l_i=8, scheme=Scheme.TILED, block_k=BK,
+                    backend="pallas", straight_through=False)
+    plan = engine.bind(params, pol, tree="cnn", strict=True,
+                       prequantize=True)
+    monkeypatch.setattr(OPS, "default_interpret", lambda: False)
+    fwd = plan.jit_forward(MODELS["googlenet"].apply)
+    arrays = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+              for a in fwd.arrays]
+    x = jax.ShapeDtypeStruct((32, 224, 224, 3), jnp.float32,
+                             sharding=one_chip)
+    text = fwd._run.lower(arrays, x).compile().as_text()
+    assert fwd.patch_convs == 1
+    assert fwd.conv_macs_issued == {32: 32 * 2_829_811_712}
+    sites = {s["name"] for s in ref.sites(cfg)}
+    assert set(plan.sites) == sites and len(sites) == 58
+    calls = [m.group(2) for m in map(T._CALL.match, text.splitlines()) if m]
+    assert len(calls) == 58
+    assert {re.match(r"jit\(run\)/(.+)/jit\(\w+\)/pallas_call$",
+                     n).group(1) for n in calls} == sites
 
 
 def test_xw_prequant_conv5_1(one_chip):

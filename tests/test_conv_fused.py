@@ -321,7 +321,7 @@ def test_narrow_conv_patch_path(h, w, c, k, stride, epilogue, patch):
     x, wk = _case(h, w, c, 16, k, k, seed=h * k + c)
     pol = _tiled(128)
     out_pol = _tiled(8) if epilogue else None
-    with ops.count_patch_convs() as tally:
+    with ops.count_conv_launches() as tally:
         out = ops.bfp_conv2d(x, wk, pol, stride, "SAME", interpret=True,
                              out_policy=out_pol)
     assert tally["patch"] == patch

@@ -250,15 +250,25 @@ def test_registry_covers_paper_models():
 
 
 def test_googlenet_multi_head_serves_main_logits():
-    """Tuple-returning models (GoogLeNet's three heads) serve head 0."""
+    """GoogLeNet serves its main (loss3) head: the registered apply
+    builds and runs no aux head, and a tuple-returning apply (the three
+    heads of ``googlenet.apply``) serves head 0, the same logits."""
     spec = MODELS["googlenet"]
     params = spec.init(KEY)
+    assert "loss1" not in params and "loss2" not in params
     x = jax.random.normal(KEY, spec.input_shape())
     eng = CnnServeEngine(params, spec.apply, EQ4, slots=1)
     r = eng.submit(image=x)
     eng.run()
-    direct = googlenet.apply(eng.plan.params, x[None], eng.plan)[0]
+    direct = googlenet.apply(eng.plan.params, x[None], eng.plan,
+                             with_aux=False)
     np.testing.assert_array_equal(r.logits, np.asarray(direct[0]))
+    heads = googlenet.init(KEY, 10, width_mult=0.125)
+    three = CnnServeEngine(heads, googlenet.apply, EQ4, slots=1)
+    r3 = three.submit(image=x)
+    three.run()
+    main, _, _ = googlenet.apply(three.plan.params, x[None], three.plan)
+    np.testing.assert_array_equal(r3.logits, np.asarray(main[0]))
 
 
 def test_launcher_exits_nonzero_when_forward_raises(monkeypatch, capsys):

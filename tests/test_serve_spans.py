@@ -129,6 +129,27 @@ def test_patch_convs_counted_per_forward(lenet, jit):
     assert flt.stats["patch_convs"] == 0
 
 
+@pytest.mark.parametrize("jit", [True, False])
+def test_conv_macs_issued_counted_per_forward(lenet, jit):
+    """``conv_macs_issued`` adds, per forward, B * OHp * OW * Kp *
+    ceil(OC / 128) * 128 over its conv launches, at the forward's own
+    bucket: c1 over its 28x28 patch tensor (Kp 128, 28 rows a program,
+    OC 16 -> 128), c2 at 14x14 (Kp 512, 9 rows a program so OHp 18)."""
+    spec, params, imgs = lenet
+    bfp = CnnServeEngine(params, spec.apply,
+                         PALLAS_TILED.with_(straight_through=False),
+                         slots=4, jit=jit)
+    flt = _engine(lenet, jit=jit)
+    for eng in (bfp, flt):
+        for img in imgs[:6]:
+            eng.submit(image=img)
+        eng.run()
+        assert eng.stats["rows"] == 4 + 2
+    per_image = 28 * 28 * 128 * 128 + 18 * 14 * 512 * 128
+    assert bfp.stats["conv_macs_issued"] == 6 * per_image
+    assert flt.stats["conv_macs_issued"] == 0
+
+
 def test_float_retry_is_a_phase(lenet):
     spec, params, imgs = lenet
 
