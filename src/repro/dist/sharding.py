@@ -28,7 +28,8 @@ from typing import Dict, Optional, Tuple, Union
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-__all__ = ["DEFAULT_RULES", "axis_rules", "shard", "current_rules",
+__all__ = ["DEFAULT_RULES", "axis_rules", "shard", "named_sharding",
+           "current_rules",
            "resolve_spec", "mesh_axis_sizes", "axis_size",
            "ShardingRuleDropped"]
 
@@ -131,19 +132,26 @@ def resolve_spec(rules: Dict[str, Axis], sizes: Dict[str, int],
     return tuple(phys)
 
 
+def named_sharding(shape: Tuple[int, ...], *logical_axes: Optional[str]
+                   ) -> Optional[NamedSharding]:
+    """The :class:`NamedSharding` that :func:`shard` gives an array of
+    ``shape``: None outside an :func:`axis_rules` context, or where the
+    axis names do not match the rank."""
+    ctx = current_rules()
+    if ctx is None or len(shape) != len(logical_axes):
+        return None
+    rules, mesh = ctx
+    phys = resolve_spec(rules, mesh_axis_sizes(mesh), shape, logical_axes)
+    return NamedSharding(mesh, P(*phys))
+
+
 def shard(x: jax.Array, *logical_axes: Optional[str]) -> jax.Array:
     """Annotate ``x`` with one logical axis name (or None) per dimension.
 
     Identity outside an :func:`axis_rules` context.  Unknown names
     replicate; indivisible dimensions replicate with a once-per-rule
-    :class:`ShardingRuleDropped` warning.
+    :class:`ShardingRuleDropped` warning.  A rank that does not match the
+    names is left as it is: never fail model code.
     """
-    ctx = current_rules()
-    if ctx is None:
-        return x
-    rules, mesh = ctx
-    if x.ndim != len(logical_axes):  # defensive: never fail model code
-        return x
-    phys = resolve_spec(rules, mesh_axis_sizes(mesh), x.shape, logical_axes)
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, P(*phys)))
+    s = named_sharding(x.shape, *logical_axes)
+    return x if s is None else jax.lax.with_sharding_constraint(x, s)

@@ -18,6 +18,11 @@ the LM decode engine has:
     barrier — defer until ``buckets[-1]`` slots are active or
     ``max_wait`` deferred steps elapse — as the measured baseline for
     ``benchmarks/serve_load.py``);
+  * a double-buffered input: while a step's last forward runs, the
+    batch of the group the next step admits first is built from the
+    queue's head and copied to the device (host images: one stack, one
+    transfer), so under a backlog the copy in leaves the path between
+    forwards; a group that is not exactly the staged one is built anew;
   * a bind-once ``engine.Plan``: policy resolution, backend selection,
     and weight pre-quantization happen at admission-time construction
     (``strict_backend=True`` rejects undeployable configs HERE);
@@ -39,8 +44,10 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import itertools
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -87,6 +94,14 @@ class ImageRequest:
     degraded: bool = False
     #: ``time.perf_counter_ns`` at ``submit`` (the queue wait's start)
     submitted_ns: int = 0
+
+
+class _Staged(NamedTuple):
+    """A batch built ahead of its forward (:meth:`CnnServeEngine._stage`)."""
+
+    reqs: Tuple[ImageRequest, ...]
+    bucket: int
+    x: jax.Array
 
 
 def default_buckets(slots: int) -> Tuple[int, ...]:
@@ -262,7 +277,9 @@ class CnnServeEngine:
         #: patch path (``kernels.ops.bfp_conv2d``; per forward it is
         #: ``patch_convs / forwards``), ``conv_macs_issued`` the MACs
         #: their conv launches issued to the MXU, padding included
-        #: (``engine.plan.BoundForward.conv_macs_issued``), ``admitted``
+        #: (``engine.plan.BoundForward.conv_macs_issued``),
+        #: ``batches_staged`` the forwards whose batch was built while
+        #: the forward before them ran (:meth:`_stage`), ``admitted``
         #: the requests given a slot and ``queue_wait_ns`` their time
         #: from submit to admission, and ``host_ns.<phase>`` the host
         #: time of each phase of :meth:`step` (``self.spans``,
@@ -274,9 +291,12 @@ class CnnServeEngine:
                                       "forwards": 0, "rows": 0,
                                       "rows_live": 0, "patch_convs": 0,
                                       "conv_macs_issued": 0,
+                                      "batches_staged": 0,
                                       "admitted": 0,
                                       "queue_wait_ns": 0}
         self.spans = SpanRecorder(self.stats)
+        #: the next step's first batch, built by :meth:`_stage`, or None
+        self._staged: Optional[_Staged] = None
 
     @property
     def ncalls(self) -> int:
@@ -393,8 +413,9 @@ class CnnServeEngine:
             r.done = True
             self.stats["expired"] += 1
 
-    def _batch_in(self, reqs: List[ImageRequest], bucket: int) -> jax.Array:
-        """The group's images as the sharded device batch of ``bucket``
+    def _build(self, reqs: Sequence[ImageRequest], bucket: int
+               ) -> jax.Array:
+        """The requests' images as the sharded device batch of ``bucket``
         rows."""
         imgs = [r.image for r in reqs]
         if len(imgs) < bucket:
@@ -407,9 +428,46 @@ class CnnServeEngine:
             # shifts; a trained model's bias pattern could otherwise own
             # an EQ2/EQ4 whole-matrix exponent from layer 2 on.)
             imgs = imgs + [imgs[0]] * (bucket - len(imgs))
-        x = jnp.stack(imgs)
         with self._sharding_ctx():
-            return DS.shard(x, *_BATCH_AXES)
+            if all(isinstance(im, np.ndarray) for im in imgs):
+                # host images: one stack into a fresh host array (no
+                # buffer an in-flight copy may still read is reused) and
+                # one transfer straight to the batch's sharding — where
+                # jnp.stack would copy each image in on its own and run
+                # a device program per image
+                x = np.stack(imgs)
+                return jax.device_put(
+                    x, DS.named_sharding(x.shape, *_BATCH_AXES))
+            return DS.shard(jnp.stack(imgs), *_BATCH_AXES)
+
+    def _batch_in(self, reqs: List[ImageRequest], bucket: int) -> jax.Array:
+        """The group's device batch: the staged one when it holds exactly
+        these requests, in this order, at this bucket; else built now."""
+        staged, self._staged = self._staged, None
+        if staged is not None and staged.bucket == bucket and \
+                len(staged.reqs) == len(reqs) and \
+                all(a is b for a, b in zip(staged.reqs, reqs)):
+            self.stats["batches_staged"] += 1
+            return staged.x
+        return self._build(reqs, bucket)
+
+    def _stage(self) -> None:
+        """Build the batch of the group the next step admits first (the
+        queue's head, as many as a group and the slots hold) while the
+        forward just dispatched runs.  Every slot frees by the end of this
+        step, so that group is exactly this head unless a request expires
+        or the queue changes meanwhile — which :meth:`_batch_in` sees as
+        a miss, never as a wrong row."""
+        n = min(self.buckets[-1], self.table.slots, len(self.table.queue))
+        reqs = tuple(itertools.islice(self.table.queue, n))
+        bucket = self._bucket_for(n)
+        try:
+            self._staged = _Staged(reqs, bucket, self._build(reqs, bucket))
+        except Exception:                         # noqa: BLE001
+            # the batch belongs to the next group, not to the one in
+            # flight: leave nothing staged, and the next _batch_in builds
+            # it again and fails the requests it belongs to if it must
+            self._staged = None
 
     def _dispatch(self, fwd: Callable[..., Any], x: jax.Array,
                   live: int) -> Any:
@@ -439,9 +497,11 @@ class CnnServeEngine:
                 self.stats["degraded_served"] += 1
             self.table.free(s)
 
-    def _run_group(self, group: List[int], degraded: bool = False) -> None:
+    def _run_group(self, group: List[int], degraded: bool = False,
+                   stage: bool = False) -> None:
         """One forward of the requests in ``group``'s slots, timed by
-        phase (``serve.batch_in``, ``.dispatch``, ``.logits_wait``,
+        phase (``serve.batch_in``, ``.dispatch``, ``.stage`` when
+        ``stage`` and the queue holds requests, ``.logits_wait``,
         ``.finish``, and ``.float_retry`` when the logits were not
         finite)."""
         span = self.spans.span
@@ -452,6 +512,9 @@ class CnnServeEngine:
             with span("serve.dispatch"):
                 out = self._dispatch(self._fb_fwd if degraded else self._fwd,
                                      x, len(reqs))
+            if stage and self.table.queue:
+                with span("serve.stage"):
+                    self._stage()
             with span("serve.logits_wait"):
                 logits = np.asarray(head_logits(out))
         except Exception as e:                    # noqa: BLE001 — slots
@@ -494,7 +557,10 @@ class CnnServeEngine:
 
         The step runs as one ``serve.step`` of :attr:`spans`; admission
         and expiry are its ``serve.admit`` phase, then each group's
-        phases follow (:meth:`_run_group`).
+        phases follow (:meth:`_run_group`).  While the last group's
+        forward runs, the next step's first batch is built from the
+        queue's head (``serve.stage``), so with a backlog the copy in of
+        every forward but the first leaves the path between forwards.
         """
         with self.spans.step():
             with self.spans.span("serve.admit"):
@@ -521,7 +587,9 @@ class CnnServeEngine:
                 return self.table.pending()
             self._waited = 0
             for i in range(0, len(active), cap):
-                self._run_group(active[i:i + cap], degraded=degraded)
+                # the last group stages the next step's first batch
+                self._run_group(active[i:i + cap], degraded=degraded,
+                                stage=i + cap >= len(active))
             return self.table.pending()
 
     def run(self) -> List[Any]:

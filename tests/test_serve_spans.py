@@ -19,6 +19,9 @@ from repro.serve.tenants import MultiTenantServer
 #: the phases of a step that runs one forward, in order
 PHASES = ["serve.admit", "serve.batch_in", "serve.dispatch",
           "serve.logits_wait", "serve.finish"]
+#: the same, where requests are still queued at the dispatch: the next
+#: step's batch is built while the forward runs
+STAGED_PHASES = PHASES[:3] + ["serve.stage"] + PHASES[3:]
 
 
 @pytest.fixture(scope="module")
@@ -55,8 +58,9 @@ def test_counters_match_steps(lenet):
     assert st["forwards"] == eng.ncalls == 3
     assert st["rows"] == 4 + 2 + 4 and st["rows_live"] == 4 + 2 + 3
     assert st["completed"] == 9
+    assert st["batches_staged"] == 1    # the second step's 2 rows
     host = _host_ns(st)
-    assert sorted(host) == sorted(PREFIX + p for p in PHASES)
+    assert sorted(host) == sorted(PREFIX + p for p in STAGED_PHASES)
     assert all(v > 0 for v in host.values()), host
     assert sum(host.values()) <= wall
     assert st["queue_wait_ns"] > 0
@@ -76,6 +80,22 @@ def test_queue_wait_counts_time_in_queue(lenet):
     assert eng.stats["queue_wait_ns"] >= 20_000_000
 
 
+def _assert_phases_tile_steps(recs, want):
+    """Each ``serve.step`` of ``recs`` (by step id) holds ``want[id]``'s
+    phases, in order, one after another and inside the step."""
+    steps = sorted((r for r in recs if r[0] == "serve.step"),
+                   key=lambda r: r[1])
+    assert [r[3] for r in steps] == list(want)
+    for _, s0, d0, k in steps:
+        phases = sorted((r for r in recs if r[0] != "serve.step"
+                         and r[3] == k), key=lambda r: r[1])
+        assert [r[0] for r in phases] == want[k]
+        assert s0 <= phases[0][1]
+        assert phases[-1][1] + phases[-1][2] <= s0 + d0
+        for a, b in zip(phases, phases[1:]):
+            assert a[1] + a[2] <= b[1]      # one after another
+
+
 def test_records_kept_only_on_request(lenet):
     _, _, imgs = lenet
     eng = _engine(lenet)
@@ -92,22 +112,27 @@ def test_records_kept_only_on_request(lenet):
     assert all(isinstance(n, str) and isinstance(s, int)
                and isinstance(d, int) and d >= 0 and isinstance(k, int)
                for n, s, d, k in recs)
-    steps = sorted((r for r in recs if r[0] == "serve.step"),
-                   key=lambda r: r[1])
-    assert [r[3] for r in steps] == [1, 2]
-    for _, s0, d0, k in steps:
-        phases = sorted((r for r in recs if r[0] != "serve.step"
-                         and r[3] == k), key=lambda r: r[1])
-        assert [r[0] for r in phases] == PHASES
-        assert s0 <= phases[0][1]
-        assert phases[-1][1] + phases[-1][2] <= s0 + d0
-        for a, b in zip(phases, phases[1:]):
-            assert a[1] + a[2] <= b[1]      # one after another
+    _assert_phases_tile_steps(recs, {1: PHASES, 2: PHASES})
     eng.spans.keep = False
     n = len(recs)
     eng.submit(image=imgs[0])
     eng.run()
     assert len(eng.spans.records) == n
+
+
+def test_staging_step_phases_tile_the_step(lenet):
+    """With requests queued at the dispatch, ``serve.stage`` runs between
+    ``serve.dispatch`` and ``serve.logits_wait``, and the phases still
+    tile the step; the step that drains the queue stages nothing."""
+    _, _, imgs = lenet
+    eng = _engine(lenet)
+    eng.spans.keep = True
+    for img in imgs[:6]:
+        eng.submit(image=img)
+    eng.run()
+    _assert_phases_tile_steps(eng.spans.records,
+                              {0: STAGED_PHASES, 1: PHASES})
+    assert eng.stats["batches_staged"] == 1
 
 
 @pytest.mark.parametrize("jit", [True, False])
