@@ -1,4 +1,4 @@
-"""Shared benchmark utilities: timing, CSV emission, smoke mode.
+"""Shared benchmark utilities: CSV emission, smoke mode, JSON records.
 
 ``benchmarks.run --smoke`` flips :data:`SMOKE` before any suite runs;
 each suite consults it to shrink shapes/grids/reps so the whole harness
@@ -6,18 +6,13 @@ finishes in CI seconds — the point is that benchmark SCRIPTS cannot rot,
 not that smoke numbers mean anything.  ``--csv PATH`` tees every
 ``emit`` row to a file (uploaded as a CI artifact).
 
-``--bench-json PATH`` additionally collects the structured
-legacy-vs-new kernel records (kernel_bench / conv_bench layer rows)
-into a JSON artifact — the pinned ``BENCH_kernels.json`` trajectory
-that ``tools/check_bench.py`` gates in CI (ISSUE 6).
+``pack_bench --bench-json PATH`` additionally collects its structured
+records (:func:`add_record`) into a JSON artifact — the pinned
+``BENCH_pack.json`` byte ratios that ``tools/check_bench.py`` gates.
 """
 from __future__ import annotations
 
-import pathlib
-import time
-from typing import Callable, Optional, TextIO
-
-import jax
+from typing import Optional, TextIO
 
 #: True under ``benchmarks.run --smoke``: tiny shapes, 1 warmup / 1 rep.
 SMOKE = False
@@ -44,63 +39,16 @@ def set_json(records: Optional[list]) -> None:
 
 
 def add_record(rec: dict) -> None:
-    """Append one structured record to the --bench-json collection
+    """Append one structured record to the ``--bench-json`` collection
     (no-op when JSON collection is off)."""
     if _JSON is not None:
         _JSON.append(rec)
-
-
-def bench_tune_cache():
-    """The repo's committed autotune cache (``tune_cache.json`` at the
-    repo root, filled by ``python -m repro.tune``) — empty cache when the
-    file is absent, so benches degrade to fallback tiles."""
-    from repro.tune.cache import TuneCache
-    p = pathlib.Path(__file__).resolve().parent.parent / "tune_cache.json"
-    return TuneCache.load(str(p))
 
 
 def bench_reps(warmup: int = 2, iters: int = 5) -> dict:
     """Requested reps, collapsed to (1, 1) in smoke mode."""
     return ({"warmup": 1, "iters": 1} if SMOKE
             else {"warmup": warmup, "iters": iters})
-
-
-def time_call(fn: Callable, *args, warmup: int = 2, iters: int = 5) -> float:
-    """Median microseconds per call (block_until_ready)."""
-    for _ in range(warmup):
-        jax.block_until_ready(fn(*args))
-    ts = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(*args))
-        ts.append(time.perf_counter() - t0)
-    ts.sort()
-    return ts[len(ts) // 2] * 1e6
-
-
-def time_pair(fn_a: Callable, fn_b: Callable, warmup: int = 1,
-              iters: int = 5) -> tuple:
-    """Interleaved median microseconds for two rival zero-arg callables.
-
-    Timing the rivals in separate blocks puts any machine drift (CPU
-    contention, thermal ramps) entirely on the a/b RATIO — exactly the
-    number the legacy-vs-new layer rows gate on.  Alternating a/b
-    samples makes drift hit both sides equally instead.
-    """
-    for _ in range(warmup):
-        jax.block_until_ready(fn_a())
-        jax.block_until_ready(fn_b())
-    ta, tb = [], []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn_a())
-        ta.append(time.perf_counter() - t0)
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn_b())
-        tb.append(time.perf_counter() - t0)
-    ta.sort()
-    tb.sort()
-    return ta[len(ta) // 2] * 1e6, tb[len(tb) // 2] * 1e6
 
 
 def emit(name: str, us_per_call: float, derived: str = ""):

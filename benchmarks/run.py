@@ -7,76 +7,47 @@ Prints ``name,us_per_call,derived`` CSV rows (benchmarks/common.emit).
   table2_scheme        paper Table 2 (eq.2 vs eq.4 accuracy, no retrain)
   table3_sweep         paper Table 3 (L_W x L_I accuracy-drop grid) + E5
   table4_nsr           paper Table 4 (per-layer SNR: measured vs model)
-  kernel_bench         E6 kernel microbench + Fig. 2 datapath sizing
   blocksize_ablation   E10 TPU K-tile block-size ablation (beyond paper)
-  engine_bench         E11 engine: cached prequant weights vs per-step
-                       re-quantization (ISSUE 1 acceptance)
-  conv_bench           E12 fused implicit-im2col conv vs im2col+GEMM
-                       (ISSUE 2 acceptance)
-  dispatch_bench       E13 bound-plan vs per-call dispatch (trace time +
-                       eager steady state; ISSUE 3 acceptance)
-  cnn_serve_bench      E14 CNN serving: requests/sec vs batch bucket
-                       size + prequant on/off (ISSUE 4 acceptance)
   faults_bench         E15 fault endurance: NSR / top-1 agreement vs
                        bit-error rate x L x target (ISSUE 7 acceptance)
   cnn_train            E16 BFP train-to-accuracy: quantized backward
                        GEMMs + compressed gradient exchange at L=4..12
                        vs float baseline (ISSUE 8 acceptance)
-  serve_load           E17 open-loop Poisson serving load: continuous
-                       vs bucket-barrier batching, p50/p99/goodput +
-                       overload behaviour (ISSUE 9 acceptance).  Its
-                       pinned trajectory lives in BENCH_serve.json,
-                       written by ``python -m benchmarks.serve_load
-                       --bench-json`` (own schema, own CI gate)
   pack_bench           E18 packed-container bytes: fixed-L vs
                        variable-width (ISSUE 10 acceptance).  Pinned
                        trajectory in BENCH_pack.json, written by
                        ``python -m benchmarks.pack_bench --bench-json``
                        (own schema ``pack-1``, own CI gate)
 
+These suites reproduce the paper's accuracy and error numbers and count
+bytes; a time they print is a host CPU time, not speed.  Speed is
+measured on the chip by
+``bench/run.py`` (cells in ``BENCHMARK.json``; findings in PERF.md).
+
 Flags:
   --smoke       tiny shapes, 1 rep — CI rot-check mode (the numbers are
                 meaningless; the scripts running end-to-end is the point)
   --csv PATH    tee every emitted row to PATH (CI uploads it)
-  --bench-json PATH
-                collect the structured legacy-vs-new kernel records
-                (kernel/conv layer rows) into PATH.  The committed
-                BENCH_kernels.json at the repo root is this artifact from
-                a full (non-smoke) run; CI regenerates it and
-                tools/check_bench.py fails on a >20% speedup regression
-                (ratios are machine-independent; absolute us are not)
-
-Roofline/dry-run numbers are produced by ``repro.launch.dryrun`` (they
-need the 512-device env) and summarized in EXPERIMENTS.md.
 """
 from __future__ import annotations
 
 import argparse
-import json
 import sys
 import time
 import traceback
 
-from benchmarks import (blocksize_ablation, cnn_serve_bench, cnn_train,
-                        common, conv_bench, dispatch_bench, engine_bench,
-                        faults_bench, kernel_bench, pack_bench, serve_load,
-                        table1_storage, table2_scheme, table3_sweep,
-                        table4_nsr)
+from benchmarks import (blocksize_ablation, cnn_train, common, faults_bench,
+                        pack_bench, table1_storage, table2_scheme,
+                        table3_sweep, table4_nsr)
 
 _ALL = {
     "table1": table1_storage.run,
     "table2": table2_scheme.run,
     "table3": table3_sweep.run,
     "table4": table4_nsr.run,
-    "kernel": kernel_bench.run,
     "blocksize": blocksize_ablation.run,
-    "engine": engine_bench.run,
-    "conv": conv_bench.run,
-    "dispatch": dispatch_bench.run,
-    "cnn_serve": cnn_serve_bench.run,
     "faults": faults_bench.run,
     "cnn_train": cnn_train.run,
-    "serve_load": serve_load.run,
     "pack": pack_bench.run,
 }
 
@@ -89,9 +60,6 @@ def main() -> None:
                     help="tiny shapes / 1 rep (CI rot check)")
     ap.add_argument("--csv", metavar="PATH",
                     help="also write CSV rows to PATH")
-    ap.add_argument("--bench-json", metavar="PATH",
-                    help="write structured legacy-vs-new kernel records "
-                         "(kernel/conv suites) to PATH")
     args = ap.parse_args()
     names = args.names or list(_ALL)
     unknown = [n for n in names if n not in _ALL]
@@ -100,8 +68,6 @@ def main() -> None:
     common.set_smoke(args.smoke)
     fh = open(args.csv, "w") if args.csv else None
     common.set_csv(fh)
-    records = [] if args.bench_json else None
-    common.set_json(records)
 
     print("name,us_per_call,derived")
     if fh:
@@ -117,14 +83,6 @@ def main() -> None:
         print(f"# {n} done in {time.time() - t0:.1f}s", flush=True)
     if fh:
         fh.close()
-    if args.bench_json:
-        doc = {"schema": 1, "mode": "smoke" if args.smoke else "full",
-               "target": "interpret", "records": records}
-        with open(args.bench_json, "w") as jf:
-            json.dump(doc, jf, indent=1, sort_keys=True)
-            jf.write("\n")
-        print(f"# wrote {len(records)} records to {args.bench_json}",
-              flush=True)
     if failures:
         sys.exit(1)
 

@@ -1,11 +1,11 @@
-"""Architecture config schema + input-shape definitions for all assigned
-architectures (system-prompt pool) and the paper's CNNs."""
+"""Architecture config schema for the LM-family architectures, and
+``reduced`` for their same-family smoke sizes."""
 from __future__ import annotations
 
 import dataclasses
 from typing import Optional, Tuple
 
-__all__ = ["LMConfig", "ShapeConfig", "SHAPES", "reduced"]
+__all__ = ["LMConfig", "reduced"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,17 +65,8 @@ class LMConfig:
     def is_encdec(self) -> bool:
         return self.encoder_layers > 0
 
-    @property
-    def sub_quadratic(self) -> bool:
-        """Eligible for long_500k (DESIGN.md §4 shape applicability)."""
-        if self.family == "ssm":
-            return True
-        if self.block_pattern is not None:   # hybrid: local attn + recurrent
-            return True
-        return self.sliding_window is not None
-
     def param_count(self) -> int:
-        """Analytic parameter count (used for 6ND model-FLOPs roofline)."""
+        """Analytic parameter count."""
         d, f, v = self.d_model, self.d_ff, self.vocab_size
         dh, h, hk = self.dh, self.n_heads, self.n_kv_heads
         attn = d * (h * dh) + 2 * d * (hk * dh) + (h * dh) * d
@@ -115,30 +106,6 @@ class LMConfig:
                     + self.n_layers * (attn + cross + ffn_params() + 3 * d))
         emb = v * d * (1 if self.tie_embeddings else 2)
         return body + emb
-
-    def active_param_count(self) -> int:
-        """Active params per token (MoE: top_k of n_experts)."""
-        if not self.is_moe:
-            return self.param_count()
-        d, f = self.d_model, self.d_ff
-        dense_like = self.param_count() - self.n_layers * (self.n_experts - self.top_k) * 3 * d * f
-        return dense_like
-
-
-@dataclasses.dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str  # "train" | "prefill" | "decode"
-
-
-SHAPES = {
-    "train_4k": ShapeConfig("train_4k", 4096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524288, 1, "decode"),
-}
 
 
 def reduced(cfg: LMConfig, n_layers: int = 2, d_model: int = 64,

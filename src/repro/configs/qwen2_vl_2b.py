@@ -1,8 +1,8 @@
 """qwen2-vl-2b [vlm] — M-RoPE, dynamic resolution.  [arXiv:2409.12191; hf]
 
-Backbone only (per assignment the vision frontend is a stub; input_specs
-provides precomputed patch embeddings).  28L d_model=1536 12H (GQA kv=2)
-d_ff=8960 vocab=151936.  M-RoPE sections (16, 24, 24) over the 64
+Backbone only (the vision frontend is a stub: ``model.forward`` takes
+precomputed patch embeddings as ``enc_feats``).  28L d_model=1536 12H
+(GQA kv=2) d_ff=8960 vocab=151936.  M-RoPE sections (16, 24, 24) over the 64
 rotary-half dims of head_dim=128.
 """
 from repro.configs.base import LMConfig

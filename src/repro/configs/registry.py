@@ -3,7 +3,7 @@ from __future__ import annotations
 
 from typing import Dict
 
-from repro.configs.base import LMConfig, SHAPES
+from repro.configs.base import LMConfig
 from repro.configs.recurrentgemma_9b import CONFIG as _recurrentgemma_9b
 from repro.configs.mistral_nemo_12b import CONFIG as _mistral_nemo_12b
 from repro.configs.minicpm_2b import CONFIG as _minicpm_2b
@@ -28,16 +28,3 @@ def get(name: str) -> LMConfig:
     if name not in ARCHS:
         raise KeyError(f"unknown arch {name!r}; known: {sorted(ARCHS)}")
     return ARCHS[name]
-
-
-def cells():
-    """All (arch, shape) dry-run cells with applicability filtering
-    (DESIGN.md §4): long_500k only for sub-quadratic archs."""
-    out = []
-    for arch, cfg in ARCHS.items():
-        for sname, shp in SHAPES.items():
-            if sname == "long_500k" and not cfg.sub_quadratic:
-                out.append((arch, sname, "skip: full quadratic attention"))
-            else:
-                out.append((arch, sname, None))
-    return out

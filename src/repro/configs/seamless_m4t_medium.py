@@ -3,7 +3,8 @@
 
 Transformer backbone only: 12L encoder + 12L decoder, d_model=1024 16H
 (MHA kv=16) d_ff=4096 vocab=256206.  The speech frontend is a stub —
-input_specs() provides precomputed frame embeddings [B, S_enc, d_model].
+``model.forward`` takes precomputed frame embeddings [B, S_enc, d_model]
+as ``enc_feats``.
 """
 from repro.configs.base import LMConfig
 

@@ -6,7 +6,7 @@ The model code annotates activations with LOGICAL axis names
 logical names to physical mesh axes with ``sharding.axis_rules``.  Outside
 an ``axis_rules`` context every annotation is the identity, so the same
 model code runs unmodified on a single CPU host (tests) and on the
-production meshes (launch.dryrun / launch.train).
+meshes the launchers build (``launch.mesh.make_mesh``).
 
 ``compress`` carries the BFP gradient wire: ``quantize_leaf`` is the
 jit-safe in-graph model, ``pack_leaf``/``wire_report`` the actual

@@ -35,9 +35,8 @@ __all__ = ["DEFAULT_RULES", "axis_rules", "shard", "named_sharding",
 
 Axis = Union[None, str, Tuple[str, ...]]
 
-#: Logical -> physical defaults for the production meshes
-#: (launch.mesh: axes ("data", "model") or ("pod", "data", "model")).
-#: ``launch.input_specs.cell_rules`` patches these per (arch x shape) cell.
+#: Logical -> physical defaults for meshes with axes ("data", "model")
+#: (``launch.mesh.make_mesh``); ``axis_rules`` overrides them.
 DEFAULT_RULES: Dict[str, Axis] = {
     "batch": "data",        # pure data parallelism
     "seq": None,            # full sequences per shard

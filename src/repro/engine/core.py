@@ -12,7 +12,7 @@ materialized im2col + :func:`gemm`:
 * ``w`` is a float matrix OR the prequant ``{"m", "s"}`` wire format
   (int8 mantissas + power-of-two scale sidecar); pre-quantized weights
   are first-class on every backend, so inference quantizes weights ONCE
-  (see ``prequantize`` / ``prequantize_cnn`` and benchmarks/engine_bench).
+  (see ``prequantize`` / ``prequantize_cnn``).
 * ``policy`` is None (float), a BFPPolicy (uniform), a PolicyMap
   (per-layer rules resolved against ``path`` — the paper's Table-3
   layer-wise assignments as config), or a bound ``Plan``
@@ -380,8 +380,8 @@ def conv2d_im2col(x: Any, w: Any, pol, stride: int = 1,
     """The materialized-im2col route: paper Fig. 1's matrix form, lowered
     through the GEMM engine (so backend selection, prequant handling, and
     fallbacks behave exactly as for any other GEMM).  :func:`conv2d`'s
-    fallback; public so A/B comparisons (benchmarks/conv_bench.py) can
-    force this route against the fused kernel.  ``pol`` is an
+    fallback; public so tests can force this route against the fused
+    kernel.  ``pol`` is an
     already-resolved BFPPolicy or None, not a PolicyMap.  Does not emit
     tap events (the :func:`conv2d` entry does, once per conv site)."""
     return _conv_im2col_exec(x, w, pol, stride, padding, key,

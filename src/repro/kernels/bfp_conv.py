@@ -20,12 +20,13 @@ Dot modes, software pipelining, prequant activations, and the epilogue
 requantizer all follow :mod:`repro.kernels.bfp_matmul` (one shared
 ``resolve_dot_impl`` / ``_block_format`` / ``_tile_dot``):
 
-* ``dot_impl``: int8 (MXU-native), int32 (L>8 / legacy), f32 (bit-exact
-  under the 2^24 bound, the fast interpret path) — all bit-identical.
-* ``pipeline=True`` skews the static K loop: the quantize of tile t+1 is
-  issued before the dot of tile t, so the VPU block-format and the MXU
-  dot have no data dependence and Mosaic can overlap them.  Accumulation
-  order is unchanged — results stay bit-identical.
+* ``dot_impl``: int8 (MXU-native), int32 (the datapath for L > 8), f32
+  (bit-exact under the 2^24 bound, the fast interpret path) — all
+  bit-identical.
+* The static K loop is skewed: the quantize of tile t+1 is issued before
+  the dot of tile t, so the VPU block-format and the MXU dot have no data
+  dependence and Mosaic can overlap them.  Accumulation order is the
+  plain 0..n_k-1, so the skew changes no bit.
 * Activation-prequant input (``xm`` int8 NHWC + ``xs`` per-(pixel,
   C-chunk) steps): requires ``bk | C``, which makes every patch-row
   K-tile exactly one (input pixel, channel-chunk) block — the patch
@@ -101,8 +102,7 @@ def _patch_rows(x_ref, *, kh: int, kw: int, stride: int, t_oh: int,
 
 
 def _make_conv_kernel(*, kh, kw, stride, t_oh, ow, bk, n_k, l_i, l_w,
-                      x_pq: bool, w_pq: bool, mode: str, pipeline: bool,
-                      out_q):
+                      x_pq: bool, w_pq: bool, mode: str, out_q):
     """Build the conv kernel body for one static configuration.
 
     Ref order: x side (1 or 2 refs), w side (1 or 2), out (1 or 2).
@@ -154,23 +154,16 @@ def _make_conv_kernel(*, kh, kw, stride, t_oh, ow, bk, n_k, l_i, l_w,
 
         bn = (wm_ref if w_pq else w_ref).shape[1]
         acc = jnp.zeros((t_oh * ow, bn), jnp.float32)
-        if pipeline:
-            # Skewed issue order: quantize tile t+1 BEFORE the dot of
-            # tile t — the block-format (VPU) and the dot (MXU) have no
-            # data dependence, so Mosaic overlaps them.  Accumulation
-            # order is unchanged (0..n_k-1): bit-identical results.
-            cur = (x_tile(0), w_tile(0))
-            for t in range(n_k):
-                nxt = (x_tile(t + 1), w_tile(t + 1)) if t + 1 < n_k \
-                    else None
-                (mx, sx), (mw, sw) = cur
-                acc = acc + _tile_dot(mx, mw, mode) * (sx * sw)
-                cur = nxt
-        else:
-            for t in range(n_k):
-                mx, sx = x_tile(t)
-                mw, sw = w_tile(t)
-                acc = acc + _tile_dot(mx, mw, mode) * (sx * sw)
+        # Skewed issue order: quantize tile t+1 BEFORE the dot of tile t
+        # — the block-format (VPU) and the dot (MXU) have no data
+        # dependence, so Mosaic overlaps them.  Accumulation order is
+        # unchanged (0..n_k-1).
+        cur = (x_tile(0), w_tile(0))
+        for t in range(n_k):
+            nxt = (x_tile(t + 1), w_tile(t + 1)) if t + 1 < n_k else None
+            (mx, sx), (mw, sw) = cur
+            acc = acc + _tile_dot(mx, mw, mode) * (sx * sw)
+            cur = nxt
 
         if out_q is None:
             o_ref[...] = acc.reshape(1, t_oh, ow, -1)
@@ -252,7 +245,7 @@ def _out_q(out_bits, out_block, bn):
 
 
 def _conv_call(x_ops, w_ops, *, kh, kw, stride, t_oh, ohp, ow, bn, bk,
-               l_i, l_w, interpret, dot_impl, pipeline, out_q):
+               l_i, l_w, interpret, dot_impl, out_q):
     """Assemble specs and launch; ``x_ops`` is (x,) or (xm, xs) NHWC,
     ``w_ops`` is (w2d,) or (wm2d, ws) GEMM view."""
     x_pq, w_pq = len(x_ops) == 2, len(w_ops) == 2
@@ -296,8 +289,7 @@ def _conv_call(x_ops, w_ops, *, kh, kw, stride, t_oh, ohp, ow, bn, bk,
 
     kernel = _make_conv_kernel(kh=kh, kw=kw, stride=stride, t_oh=t_oh,
                                ow=ow, bk=bk, n_k=n_k, l_i=l_i, l_w=l_w,
-                               x_pq=x_pq, w_pq=w_pq, mode=mode,
-                               pipeline=pipeline, out_q=out_q)
+                               x_pq=x_pq, w_pq=w_pq, mode=mode, out_q=out_q)
     blocks = [((hp, wp, c), x_ops[0].dtype), ((kp, bn), w_ops[0].dtype),
               ((t_oh * ow, bn), jnp.float32)]
     if x_pq:
@@ -321,8 +313,7 @@ def _conv_call(x_ops, w_ops, *, kh, kw, stride, t_oh, ohp, ow, bn, bk,
 
 
 _STATIC = ("kh", "kw", "stride", "t_oh", "ohp", "ow", "bn", "bk", "l_i",
-           "l_w", "interpret", "dot_impl", "pipeline", "out_bits",
-           "out_block")
+           "l_w", "interpret", "dot_impl", "out_bits", "out_block")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
@@ -330,7 +321,7 @@ def bfp_conv2d_pallas(x: jax.Array, w2d: jax.Array, *, kh: int, kw: int,
                       stride: int, t_oh: int, ohp: int, ow: int, bn: int,
                       bk: int, l_i: int = 8, l_w: int = 8,
                       interpret: bool = False, dot_impl: str = "auto",
-                      pipeline: bool = True, out_bits: int | None = None,
+                      out_bits: int | None = None,
                       out_block: int | None = None):
     """Fused implicit-im2col BFP conv.
 
@@ -347,7 +338,6 @@ def bfp_conv2d_pallas(x: jax.Array, w2d: jax.Array, *, kh: int, kw: int,
     return _conv_call((x,), (w2d,), kh=kh, kw=kw, stride=stride,
                       t_oh=t_oh, ohp=ohp, ow=ow, bn=bn, bk=bk, l_i=l_i,
                       l_w=l_w, interpret=interpret, dot_impl=dot_impl,
-                      pipeline=pipeline,
                       out_q=_out_q(out_bits, out_block, bn))
 
 
@@ -358,7 +348,6 @@ def bfp_conv2d_prequant_pallas(x: jax.Array, wm2d: jax.Array,
                                bn: int, bk: int, l_i: int = 8,
                                l_w: int = 8, interpret: bool = False,
                                dot_impl: str = "auto",
-                               pipeline: bool = True,
                                out_bits: int | None = None,
                                out_block: int | None = None):
     """Prequant fused conv: weights arrive as int8 GEMM-view mantissas
@@ -375,7 +364,6 @@ def bfp_conv2d_prequant_pallas(x: jax.Array, wm2d: jax.Array,
     return _conv_call((x,), (wm2d, ws), kh=kh, kw=kw, stride=stride,
                       t_oh=t_oh, ohp=ohp, ow=ow, bn=bn, bk=bk, l_i=l_i,
                       l_w=l_w, interpret=interpret, dot_impl=dot_impl,
-                      pipeline=pipeline,
                       out_q=_out_q(out_bits, out_block, bn))
 
 
@@ -386,7 +374,6 @@ def bfp_conv2d_xprequant_pallas(xm: jax.Array, xs: jax.Array,
                                 bn: int, bk: int, l_i: int = 8,
                                 l_w: int = 8, interpret: bool = False,
                                 dot_impl: str = "auto",
-                                pipeline: bool = True,
                                 out_bits: int | None = None,
                                 out_block: int | None = None):
     """Prequant ACTIVATIONS: xm int8 NHWC [B,Hp,Wp,C] + xs f32 steps
@@ -406,7 +393,6 @@ def bfp_conv2d_xprequant_pallas(xm: jax.Array, xs: jax.Array,
     return _conv_call((xm, xs), (w2d,), kh=kh, kw=kw, stride=stride,
                       t_oh=t_oh, ohp=ohp, ow=ow, bn=bn, bk=bk, l_i=l_i,
                       l_w=l_w, interpret=interpret, dot_impl=dot_impl,
-                      pipeline=pipeline,
                       out_q=_out_q(out_bits, out_block, bn))
 
 
@@ -418,7 +404,6 @@ def bfp_conv2d_xwprequant_pallas(xm: jax.Array, xs: jax.Array,
                                  l_i: int = 8, l_w: int = 8,
                                  interpret: bool = False,
                                  dot_impl: str = "auto",
-                                 pipeline: bool = True,
                                  out_bits: int | None = None,
                                  out_block: int | None = None):
     """Both sides prequantized — the steady state of a conv->conv chain
@@ -440,5 +425,4 @@ def bfp_conv2d_xwprequant_pallas(xm: jax.Array, xs: jax.Array,
     return _conv_call((xm, xs), (wm2d, ws), kh=kh, kw=kw, stride=stride,
                       t_oh=t_oh, ohp=ohp, ow=ow, bn=bn, bk=bk, l_i=l_i,
                       l_w=l_w, interpret=interpret, dot_impl=dot_impl,
-                      pipeline=pipeline,
                       out_q=_out_q(out_bits, out_block, bn))

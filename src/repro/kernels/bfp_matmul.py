@@ -22,9 +22,9 @@ Dot implementations (``dot_impl``, static):
   (``preferred_element_type``): the MXU's native 8-bit systolic path.
   Requires every inline-quantized operand to have L <= 8 (prequant
   mantissas are int8 by wire contract regardless of the stated L).
-* ``"int32"`` — operands widened to int32 before the dot.  The only
-  legal mode for L > 8; also the pre-ISSUE-6 behavior, kept as the
-  like-for-like "legacy" baseline in benchmarks.
+* ``"int32"`` — operands widened to int32 before the dot: the datapath
+  for L > 8, the only mode legal there.  Mosaic refuses an int32 dot
+  when compiling for the v5e, so it runs in the interpreter only.
 * ``"f32"`` — mantissas kept/cast to f32 and dotted in f32.  BIT-exact
   whenever ``bk * (2^(L_I-1)-1) * (2^(L_W-1)-1) <= 2^24``: every product
   and partial sum is an integer of magnitude <= 2^24, all exactly
@@ -37,13 +37,12 @@ Dot implementations (``dot_impl``, static):
 All modes produce bit-identical outputs (tests force each mode and
 assert equality), so mode choice is purely a speed decision.
 
-Pipelining (``pipeline=True``, static): tiles are staged through a
-2-slot VMEM scratch with a one-step skew — grid step k quantizes tile k
-into slot k%2 and dots tile k-1 from slot (k-1)%2 (the last step dots
-both).  Quantization (VPU) of tile k then has no data dependence on the
-dot (MXU) of tile k-1, so Mosaic can overlap them; accumulation order is
-unchanged (tile 0, 1, ..., n_k-1), keeping results bit-identical to the
-unpipelined kernel.
+Pipelining: tiles are staged through a 2-slot VMEM scratch with a
+one-step skew — grid step k quantizes tile k into slot k%2 and dots tile
+k-1 from slot (k-1)%2 (the last step dots both).  Quantization (VPU) of
+tile k then has no data dependence on the dot (MXU) of tile k-1, so
+Mosaic can overlap them; accumulation order is unchanged (tile 0, 1,
+..., n_k-1), so the result is that of the plain tile-by-tile sum.
 
 Epilogue requantization (``out_bits``/``out_block``, static): instead of
 storing the fp32 accumulator, the kernel block-formats it per
@@ -181,11 +180,11 @@ def _requant_store(acc: jax.Array, om_ref, os_ref, *, out_bits: int,
 
 
 def _make_matmul_kernel(*, l_i: int, l_w: int, n_k: int, x_pq: bool,
-                        w_pq: bool, mode: str, pipeline: bool, out_q):
+                        w_pq: bool, mode: str, out_q):
     """Build the kernel body for one static configuration.
 
     Ref order: x side (1 or 2 refs), w side (1 or 2), out (1 or 2),
-    accumulator scratch, then (pipeline only) the four staging buffers.
+    accumulator scratch, then the four staging buffers.
     """
     x_dt = _mantissa_dtype(mode, l_i, x_pq)
     w_dt = _mantissa_dtype(mode, l_w, w_pq)
@@ -205,8 +204,7 @@ def _make_matmul_kernel(*, l_i: int, l_w: int, n_k: int, x_pq: bool,
         else:
             o_ref = next(it)
         acc_ref = next(it)
-        if pipeline:
-            mxb, sxb, mwb, swb = next(it), next(it), next(it), next(it)
+        mxb, sxb, mwb, swb = next(it), next(it), next(it), next(it)
 
         k_step = pl.program_id(2)
 
@@ -236,16 +234,6 @@ def _make_matmul_kernel(*, l_i: int, l_w: int, n_k: int, x_pq: bool,
             else:
                 _requant_store(acc_ref[...], om_ref, os_ref,
                                out_bits=out_q[0], out_block=out_q[1])
-
-        if not pipeline:
-            mx, sx = load_x()
-            mw, sw = load_w()
-            accum(mx, sx, mw, sw)
-
-            @pl.when(k_step == n_k - 1)
-            def _store():
-                store()
-            return
 
         # Skewed double buffer: stage tile k into slot k%2, dot tile k-1
         # from the other slot.  Quantize(k) has no dependence on
@@ -294,7 +282,7 @@ def _resolve_bk(bk, b, k, n, l_sum):
 
 
 def _matmul_call(x_ops, w_ops, *, b, k, n, l_i, l_w, bm, bn, bk, interpret,
-                 dot_impl, pipeline, out_q):
+                 dot_impl, out_q):
     """Assemble specs and launch; ``x_ops``/``w_ops`` are (float,) or
     (mantissa, steps) operand tuples; ``out_q`` is None or
     (out_bits, out_block)."""
@@ -337,18 +325,16 @@ def _matmul_call(x_ops, w_ops, *, b, k, n, l_i, l_w, bm, bn, bk, interpret,
             jax.ShapeDtypeStruct((n // bq, b, 1), jnp.float32),
         ]
 
-    scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
-    if pipeline:
-        scratch += [
-            pltpu.VMEM((2, bm, bk), _mantissa_dtype(mode, l_i, x_pq)),
-            pltpu.VMEM((2, bm, 1), jnp.float32),
-            pltpu.VMEM((2, bk, bn), _mantissa_dtype(mode, l_w, w_pq)),
-            pltpu.VMEM((2, 1, bn), jnp.float32),
-        ]
+    scratch = [
+        pltpu.VMEM((bm, bn), jnp.float32),
+        pltpu.VMEM((2, bm, bk), _mantissa_dtype(mode, l_i, x_pq)),
+        pltpu.VMEM((2, bm, 1), jnp.float32),
+        pltpu.VMEM((2, bk, bn), _mantissa_dtype(mode, l_w, w_pq)),
+        pltpu.VMEM((2, 1, bn), jnp.float32),
+    ]
 
     kernel = _make_matmul_kernel(l_i=l_i, l_w=l_w, n_k=n_k, x_pq=x_pq,
-                                 w_pq=w_pq, mode=mode, pipeline=pipeline,
-                                 out_q=out_q)
+                                 w_pq=w_pq, mode=mode, out_q=out_q)
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -371,15 +357,14 @@ def _out_q(out_bits, out_block, bn):
 
 
 _STATIC = ("l_i", "l_w", "bm", "bn", "bk", "interpret", "dot_impl",
-           "pipeline", "out_bits", "out_block")
+           "out_bits", "out_block")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def bfp_matmul_pallas(x: jax.Array, w: jax.Array, *, l_i: int = 8,
                       l_w: int = 8, bm: int = 128, bn: int = 128,
                       bk: int | None = None, interpret: bool = False,
-                      dot_impl: str = "auto", pipeline: bool = True,
-                      out_bits: int | None = None,
+                      dot_impl: str = "auto", out_bits: int | None = None,
                       out_block: int | None = None):
     """x[B,K] @ w[K,N] through the fused BFP datapath.
 
@@ -396,7 +381,7 @@ def bfp_matmul_pallas(x: jax.Array, w: jax.Array, *, l_i: int = 8,
     bk = _resolve_bk(bk, b, k, n, l_i + l_w)
     return _matmul_call((x,), (w,), b=b, k=k, n=n, l_i=l_i, l_w=l_w,
                         bm=bm, bn=bn, bk=bk, interpret=interpret,
-                        dot_impl=dot_impl, pipeline=pipeline,
+                        dot_impl=dot_impl,
                         out_q=_out_q(out_bits, out_block, bn))
 
 
@@ -405,7 +390,7 @@ def bfp_matmul_prequant_pallas(x: jax.Array, wm: jax.Array, ws: jax.Array,
                                *, l_i: int = 8, l_w: int = 8, bm: int = 128,
                                bn: int = 128, bk: int | None = None,
                                interpret: bool = False,
-                               dot_impl: str = "auto", pipeline: bool = True,
+                               dot_impl: str = "auto",
                                out_bits: int | None = None,
                                out_block: int | None = None):
     """x[B,K] @ prequant weight (int8 mantissa [K,N] + steps [K//bk,N]).
@@ -427,7 +412,7 @@ def bfp_matmul_prequant_pallas(x: jax.Array, wm: jax.Array, ws: jax.Array,
                          f"{wm.dtype}")
     return _matmul_call((x,), (wm, ws), b=b, k=k, n=n, l_i=l_i, l_w=l_w,
                         bm=bm, bn=bn, bk=bk, interpret=interpret,
-                        dot_impl=dot_impl, pipeline=pipeline,
+                        dot_impl=dot_impl,
                         out_q=_out_q(out_bits, out_block, bn))
 
 
@@ -436,7 +421,7 @@ def bfp_matmul_xprequant_pallas(xm: jax.Array, xs: jax.Array, w: jax.Array,
                                 *, l_i: int = 8, l_w: int = 8, bm: int = 128,
                                 bn: int = 128, bk: int | None = None,
                                 interpret: bool = False,
-                                dot_impl: str = "auto", pipeline: bool = True,
+                                dot_impl: str = "auto",
                                 out_bits: int | None = None,
                                 out_block: int | None = None):
     """Prequant ACTIVATIONS (int8 mantissa [B,K] + steps [B,K//bk]) @
@@ -456,7 +441,7 @@ def bfp_matmul_xprequant_pallas(xm: jax.Array, xs: jax.Array, w: jax.Array,
                          f"mantissas, got {xm.dtype}")
     return _matmul_call((xm, xs), (w,), b=b, k=k, n=n, l_i=l_i, l_w=l_w,
                         bm=bm, bn=bn, bk=bk, interpret=interpret,
-                        dot_impl=dot_impl, pipeline=pipeline,
+                        dot_impl=dot_impl,
                         out_q=_out_q(out_bits, out_block, bn))
 
 
@@ -467,7 +452,6 @@ def bfp_matmul_xwprequant_pallas(xm: jax.Array, xs: jax.Array,
                                  bn: int = 128, bk: int | None = None,
                                  interpret: bool = False,
                                  dot_impl: str = "auto",
-                                 pipeline: bool = True,
                                  out_bits: int | None = None,
                                  out_block: int | None = None):
     """Both sides prequantized — the steady state of a bound plan chain:
@@ -490,5 +474,5 @@ def bfp_matmul_xwprequant_pallas(xm: jax.Array, xs: jax.Array,
                          f"{xm.dtype} / {wm.dtype}")
     return _matmul_call((xm, xs), (wm, ws), b=b, k=k, n=n, l_i=l_i,
                         l_w=l_w, bm=bm, bn=bn, bk=bk, interpret=interpret,
-                        dot_impl=dot_impl, pipeline=pipeline,
+                        dot_impl=dot_impl,
                         out_q=_out_q(out_bits, out_block, bn))

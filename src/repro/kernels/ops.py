@@ -138,16 +138,15 @@ def bfp_matmul(x2d: ActOrArray, w: jax.Array, policy: BFPPolicy,
                interpret: Optional[bool] = None, *,
                out_policy: Optional[BFPPolicy] = None,
                tiles: Optional[Tuple[int, int, int]] = None,
-               dot_impl: str = "auto", pipeline: bool = True) -> ActOrArray:
+               dot_impl: str = "auto") -> ActOrArray:
     """x2d[B,K] @ w[K,N] via the fused Pallas kernel (Scheme.TILED).
 
     Pads every dim to tile multiples (zero K-padding is exact: zero
     mantissas contribute nothing; padded rows/cols are sliced off).
     ``x2d`` may be an activation-prequant dict (previous layer's
     epilogue output); ``out_policy`` requests requantized {"m","s"}
-    output for the NEXT layer.  ``dot_impl``/``pipeline`` pass through
-    to the kernel (benchmarks/tests force the legacy ``"int32"`` +
-    unpipelined datapath; every combination is bit-identical).
+    output for the NEXT layer.  ``dot_impl`` passes through to the kernel
+    (tests force each datapath; every one is bit-identical).
     """
     if interpret is None:
         interpret = default_interpret()
@@ -171,12 +170,12 @@ def bfp_matmul(x2d: ActOrArray, w: jax.Array, policy: BFPPolicy,
         out = bfp_matmul_xprequant_pallas(
             xm, xs, wp, l_i=policy.l_i, l_w=policy.l_w, bm=bm, bn=bn,
             bk=bk, interpret=interpret, dot_impl=dot_impl,
-            pipeline=pipeline, out_bits=ob, out_block=obk)
+            out_bits=ob, out_block=obk)
     else:
         xp = _pad_to(x2d.astype(jnp.float32), (bm, bk))
         out = bfp_matmul_pallas(
             xp, wp, l_i=policy.l_i, l_w=policy.l_w, bm=bm, bn=bn, bk=bk,
-            interpret=interpret, dot_impl=dot_impl, pipeline=pipeline,
+            interpret=interpret, dot_impl=dot_impl,
             out_bits=ob, out_block=obk)
     return _finish_gemm(out, b, n, out_policy, fused_q)
 
@@ -186,8 +185,7 @@ def bfp_matmul_prequant(x2d: ActOrArray, wm: jax.Array, ws: jax.Array,
                         interpret: Optional[bool] = None, *,
                         out_policy: Optional[BFPPolicy] = None,
                         tiles: Optional[Tuple[int, int, int]] = None,
-                        dot_impl: str = "auto",
-                        pipeline: bool = True) -> ActOrArray:
+                        dot_impl: str = "auto") -> ActOrArray:
     """x2d[B,K] @ prequant weight via the sidecar-consuming kernel.
 
     ``wm``: int8 mantissa [K, N]; ``ws``: f32 power-of-two steps
@@ -224,13 +222,13 @@ def bfp_matmul_prequant(x2d: ActOrArray, wm: jax.Array, ws: jax.Array,
         out = bfp_matmul_xwprequant_pallas(
             xm, xs, wmp, wsp, l_i=policy.l_i, l_w=policy.l_w, bm=bm,
             bn=bn, bk=bk, interpret=interpret, dot_impl=dot_impl,
-            pipeline=pipeline, out_bits=ob, out_block=obk)
+            out_bits=ob, out_block=obk)
     else:
         xp = _pad_to(x2d.astype(jnp.float32), (bm, bk))
         out = bfp_matmul_prequant_pallas(
             xp, wmp, wsp, l_i=policy.l_i, l_w=policy.l_w, bm=bm, bn=bn,
             bk=bk, interpret=interpret, dot_impl=dot_impl,
-            pipeline=pipeline, out_bits=ob, out_block=obk)
+            out_bits=ob, out_block=obk)
     return _finish_gemm(out, b, n, out_policy, fused_q)
 
 
@@ -378,7 +376,7 @@ def bfp_conv2d(x: ActOrArray, w_hwio: jax.Array, policy: BFPPolicy,
                interpret: Optional[bool] = None, *,
                out_policy: Optional[BFPPolicy] = None,
                tiles: Optional[Tuple[int, int]] = None,
-               dot_impl: str = "auto", pipeline: bool = True) -> ActOrArray:
+               dot_impl: str = "auto") -> ActOrArray:
     """NHWC conv through the fused implicit-im2col kernel (Scheme.TILED).
 
     x: [B, H, W, C] float — or an activation-prequant dict (int8 NHWC
@@ -437,7 +435,7 @@ def bfp_conv2d(x: ActOrArray, w_hwio: jax.Array, policy: BFPPolicy,
     kwargs = dict(kh=kh, kw=kw, stride=stride, t_oh=t_oh, ohp=ohp, ow=ow,
                   bn=bn, bk=bk, l_i=policy.l_i, l_w=policy.l_w,
                   interpret=interpret, dot_impl=dot_impl,
-                  pipeline=pipeline, out_bits=ob, out_block=obk)
+                  out_bits=ob, out_block=obk)
     if x_pq:
         xm, xs = _pad_act_nhwc(x, pads)
         out = bfp_conv2d_xprequant_pallas(xm, xs, w2d, **kwargs)
@@ -453,8 +451,7 @@ def bfp_conv2d_prequant(x: ActOrArray, wm_hwio: jax.Array, ws: jax.Array,
                         interpret: Optional[bool] = None, *,
                         out_policy: Optional[BFPPolicy] = None,
                         tiles: Optional[Tuple[int, int]] = None,
-                        dot_impl: str = "auto",
-                        pipeline: bool = True) -> ActOrArray:
+                        dot_impl: str = "auto") -> ActOrArray:
     """NHWC conv with pre-quantized weights (int8 HWIO mantissa + GEMM-view
     step sidecar [K//bk, OC], core.prequant wire format).
 
@@ -501,7 +498,7 @@ def bfp_conv2d_prequant(x: ActOrArray, wm_hwio: jax.Array, ws: jax.Array,
     kwargs = dict(kh=kh, kw=kw, stride=stride, t_oh=t_oh, ohp=ohp, ow=ow,
                   bn=bn, bk=bk, l_i=policy.l_i, l_w=policy.l_w,
                   interpret=interpret, dot_impl=dot_impl,
-                  pipeline=pipeline, out_bits=ob, out_block=obk)
+                  out_bits=ob, out_block=obk)
     if x_pq:
         xm, xs = _pad_act_nhwc(x, pads)
         out = bfp_conv2d_xwprequant_pallas(xm, xs, wm2d, wsp, **kwargs)

@@ -4,8 +4,7 @@ Declarative sequential spec so the NSR analysis driver (paper Table 4) can
 walk layer-by-layer.  ``width_mult``/``input_hw`` let tests run a reduced
 config of the same family.  Convs execute through ``engine.conv2d`` —
 the fused implicit-im2col Pallas kernel on the pallas backend (no
-materialized patch matrix; benchmarks/conv_bench.py models the HBM cut
-on exactly these layer shapes).
+materialized patch matrix).
 """
 from __future__ import annotations
 

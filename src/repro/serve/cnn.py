@@ -16,8 +16,8 @@ the LM decode engine has:
     per request count, and a partially-filled step RUNS instead of
     waiting behind a bucket barrier (``batching="bucket"`` keeps the
     barrier — defer until ``buckets[-1]`` slots are active or
-    ``max_wait`` deferred steps elapse — as the measured baseline for
-    ``benchmarks/serve_load.py``);
+    ``max_wait`` deferred steps elapse — as the baseline the load
+    harness ``serve.load`` compares against);
   * a double-buffered input: while a step's last forward runs, the
     batch of the group the next step admits first is built from the
     queue's head and copied to the device (host images: one stack, one

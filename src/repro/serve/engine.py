@@ -8,13 +8,12 @@ step is shape-stable), with PREFILL CHUNKED INTO THE STEP LOOP — an
 admission consumes at most ``prefill_chunk`` prompt tokens per engine
 step, so a long-prompt admission never stalls in-flight decodes behind
 ``len(prompt)`` jitted calls (``batching="bucket"`` keeps the legacy
-blocking-prefill behaviour as the measured baseline for
-``benchmarks/serve_load.py``).  BFP weight pre-quantization
+blocking-prefill behaviour as the baseline the load harness
+``serve.load`` compares against).  BFP weight pre-quantization
 (``prequant=`` or an already-converted param tree) is the paper's
 deployment mode, where weights live in HBM as int8 mantissas + exponent
 sidecars, every GEMM runs the fixed-point datapath, and quantization
-happens ONCE at engine construction, not per decode step
-(benchmarks/engine_bench.py measures the difference).  ``policy`` may be
+happens ONCE at engine construction, not per decode step.  ``policy`` may be
 a per-layer ``repro.engine.PolicyMap``; at construction it is bound into
 an ``engine.Plan`` (``self.plan``) so rule resolution and backend
 selection also happen once, at admission-time weight load, and
@@ -130,8 +129,8 @@ class ServeEngine:
     a barrier in front of in-flight work.  ``batching="bucket"`` keeps
     the legacy behaviour — admission runs the WHOLE prompt's jitted
     prefill before any active slot decodes — as the bucket-barrier
-    baseline the load harness (``serve.load`` /
-    ``benchmarks/serve_load.py``) measures continuous batching against.
+    baseline the load harness (``serve.load``) measures continuous
+    batching against.
 
     Row independence makes both modes bit-identical per request to solo
     serving (pinned by tests/test_system.py + tests/test_serve_continuous

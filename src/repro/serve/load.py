@@ -10,8 +10,8 @@ through it on a virtual clock:
   * :func:`poisson_arrivals` — a seeded Poisson process (exponential
     inter-arrival gaps) over a weighted mix of request kinds, each kind
     carrying its own payload shape and relative deadline.  Deterministic
-    given ``seed``: the pinned ``BENCH_serve.json`` trajectory replays
-    the exact same trace on any machine;
+    given ``seed``: the same seed replays the exact same trace on any
+    machine;
   * :class:`VirtualClock` — the injectable engine clock the driver owns.
     Time advances by ``call_cost`` per jitted engine call
     (``engine.ncalls``, one whole-batch decode_step / batched CNN
@@ -23,8 +23,7 @@ through it on a virtual clock:
   * :func:`run_open_loop` — submits due arrivals, steps the engine,
     collects completions, and folds everything into a :class:`LoadReport`
     (p50/p99/mean latency, goodput, shed/expired/failed counts, degraded
-    service) whose :meth:`~LoadReport.row` is the ``BENCH_serve.json``
-    record body.
+    service) whose :meth:`~LoadReport.row` is a JSON-safe record.
 
 The engine must be constructed with ``clock=<the VirtualClock>`` so
 deadline expiry sees the same timeline the driver advances.
@@ -142,7 +141,7 @@ class LoadReport:
         default_factory=dict)
 
     def row(self) -> Dict[str, Any]:
-        """JSON-safe dict — the ``BENCH_serve.json`` record body."""
+        """JSON-safe dict of the report."""
         def clean(v):
             if isinstance(v, float):
                 return round(v, 6)

@@ -1,12 +1,10 @@
 """Hillclimb autotuner for BFP kernel tile configs.
 
-The measure-and-cache shape follows ``launch/hillclimb.py``: each named
-candidate is measured (median wall-clock over a few calls, after a
+Each candidate is measured (median wall-clock over a few calls, after a
 warmup that also pays compilation), results land in a persistent cache,
-and already-cached sites are skipped.  Here the variants are not
-hand-named though — the tuner walks the power-of-two tile lattice
-greedily: evaluate the fallback config, then all single-axis x2 / /2
-neighbors, move to the best, repeat until no neighbor wins (or
+and already-cached sites are skipped.  The tuner walks the power-of-two
+tile lattice greedily: evaluate the fallback config, then all single-axis
+x2 / /2 neighbors, move to the best, repeat until no neighbor wins (or
 ``max_steps`` evaluations).
 
 Constraints baked into the neighborhood (never evaluated, not just
@@ -100,9 +98,9 @@ def tune_gemm(b: int, k: int, n: int, policy, *, cache: TuneCache,
               w: Optional[jax.Array] = None) -> Dict[str, Any]:
     """Tune (bm, bn, bk) for one GEMM site; returns the cache entry.
 
-    Already-cached sites return immediately (the launch/hillclimb.py
-    skip-if-cached shape).  ``bk`` only moves when ``policy.block_k`` is
-    None; a pinned block is the BFP block and stays fixed.
+    Already-cached sites return immediately.  ``bk`` only moves when
+    ``policy.block_k`` is None; a pinned block is the BFP block and stays
+    fixed.
     """
     from repro.kernels import ops  # late: ops imports tune.tables
 

@@ -1,11 +1,8 @@
-"""Canonical benchmark layer shapes — VGG-16 / ResNet-18 hot layers.
-
-One table shared by the autotuner CLI (``python -m repro.tune``) and
-``benchmarks/kernel_bench.py`` / ``conv_bench.py``, so tuned entries and
-pinned BENCH_kernels.json rows key on exactly the same problems.
+"""Canonical layer shapes — VGG-16 / ResNet-18 hot layers — that the
+autotuner CLI (``python -m repro.tune``) tunes.
 
 Conv shapes are (name, C, OC, kh, stride); spatial extent comes from the
-benchmark's ``hw`` (32 full / 8 smoke) so VGG's 224x224 layers stay
+CLI's ``hw`` (32 full / 8 smoke) so VGG's 224x224 layers stay
 runnable in interpret mode.  GEMM shapes are the im2col views of three
 representative convs plus the VGG classifier tail at batch 64.
 """
@@ -13,7 +10,7 @@ from __future__ import annotations
 
 __all__ = ["CONV_LAYERS", "GEMM_LAYERS"]
 
-#: (name, in_ch, out_ch, k, stride) — benchmark picks H=W=hw.
+#: (name, in_ch, out_ch, k, stride) — the CLI picks H=W=hw.
 CONV_LAYERS = (
     ("vgg16/conv1_1", 3, 64, 3, 1),
     ("vgg16/conv2_1", 64, 128, 3, 1),

@@ -72,12 +72,20 @@ def _compiles(fn, shapes, sharding) -> None:
     assert 'custom_call_target="tpu_custom_call"' in text
 
 
-@pytest.mark.parametrize("pipeline", [True, False])
-def test_fused_gemm_fc6(one_chip, pipeline):
-    _compiles(lambda x, w: MM.bfp_matmul_pallas(
-        x, w, bm=BATCH, bn=128, bk=BK, pipeline=pipeline),
-        [((BATCH, FC6_K), jnp.float32), ((FC6_K, FC6_N), jnp.float32)],
-        one_chip)
+@pytest.mark.parametrize("k", [FC6_K, FC6_K - 64],
+                         ids=["fc6", "k_unaligned"])
+def test_fused_gemm_fc6(one_chip, monkeypatch, k):
+    """The fused GEMM with float weights on the int8 datapath (``auto``
+    on the chip): at fc6's K, and at a K no multiple of ``block_k``,
+    which ``ops.bfp_matmul`` zero-pads to the next block.  (The int32
+    datapath does not compile for the v5e: Mosaic refuses an int32
+    dot.)"""
+    monkeypatch.setattr(OPS, "default_interpret", lambda: False)
+    pol = BFPPolicy(l_w=8, l_i=8, scheme=Scheme.TILED, block_k=BK,
+                    backend="pallas", straight_through=False)
+    _compiles(lambda x, w: OPS.bfp_matmul(x, w, pol, tiles=(BATCH, 128, BK)),
+              [((BATCH, k), jnp.float32), ((k, FC6_N), jnp.float32)],
+              one_chip)
 
 
 @pytest.mark.parametrize("out_bits", [None, 8])

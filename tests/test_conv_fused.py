@@ -207,42 +207,49 @@ def test_aligned_tile_shared_floor():
     assert m.shape == (100, 256) and e.shape == (100, 2)
 
 # ---------------------------------------------------------------------------
-# ISSUE 6 — dot modes, pipelining, fused requantize epilogue (conv)
+# ISSUE 6 — dot modes, fused requantize epilogue (conv)
 # ---------------------------------------------------------------------------
 
 from repro.core.prequant import dequantize_act, prequant_act  # noqa: E402
 
 
+def _conv(weights, x, wk, pol, stride, **kw):
+    """``ops`` conv with float weights (the fused kernel) or with the
+    weights prequantized (the sidecar kernel a bound plan launches)."""
+    if weights == "float":
+        return ops.bfp_conv2d(x, wk, pol, stride, "SAME", True, **kw)
+    pq = prequant_conv_leaf(wk, pol)
+    return ops.bfp_conv2d_prequant(x, pq["m"], pq["s"], pol, stride,
+                                   "SAME", True, **kw)
+
+
 @pytest.mark.parametrize("dot_impl", ["int8", "int32", "f32"])
-@pytest.mark.parametrize("pipeline", [False, True])
-def test_conv_dot_modes_bit_identical(dot_impl, pipeline):
-    """Every conv dot datapath x pipelining == the legacy
-    int32/unpipelined kernel bit for bit, and == the oracle."""
+@pytest.mark.parametrize("weights", ["float", "prequant"])
+def test_conv_dot_modes_bit_identical(dot_impl, weights):
+    """Every conv dot datapath, with float or prequantized weights, ==
+    the int32 datapath on the same weight form bit for bit, and == the
+    oracle."""
     x, wk = _case(8, 8, 8, 10, 3, 3, seed=21)
     pol = _tiled(24)
-    out = ops.bfp_conv2d(x, wk, pol, 1, "SAME", True,
-                         dot_impl=dot_impl, pipeline=pipeline)
-    base = ops.bfp_conv2d(x, wk, pol, 1, "SAME", True,
-                          dot_impl="int32", pipeline=False)
+    out = _conv(weights, x, wk, pol, 1, dot_impl=dot_impl)
+    base = _conv(weights, x, wk, pol, 1, dot_impl="int32")
     np.testing.assert_array_equal(np.asarray(out), np.asarray(base))
     out_r = ref.bfp_conv2d_ref(x, wk, 8, 8, 24, 1, "SAME")
     np.testing.assert_allclose(np.asarray(out), np.asarray(out_r),
                                rtol=1e-6, atol=1e-6)
 
 
-@pytest.mark.parametrize("pipeline", [False, True])
+@pytest.mark.parametrize("weights", ["float", "prequant"])
 @pytest.mark.parametrize("stride", [1, 2])
-def test_conv_epilogue_requant_bit_identical(pipeline, stride):
-    """Fused conv epilogue == conv-then-prequant_act, bit for bit,
-    including the NHWC sidecar shape (blocks along OC per pixel)."""
+def test_conv_epilogue_requant_bit_identical(weights, stride):
+    """Fused conv epilogue == conv-then-prequant_act, bit for bit, with
+    float or prequantized weights, including the NHWC sidecar shape
+    (blocks along OC per pixel)."""
     x, wk = _case(8, 8, 8, 16, 3, 3, seed=22)
     pol = _tiled(24)
     out_pol = _tiled(8)
-    fused = ops.bfp_conv2d(x, wk, pol, stride, "SAME", True,
-                           out_policy=out_pol, pipeline=pipeline)
-    two = prequant_act(
-        ops.bfp_conv2d(x, wk, pol, stride, "SAME", True,
-                       pipeline=pipeline), out_pol)
+    fused = _conv(weights, x, wk, pol, stride, out_policy=out_pol)
+    two = prequant_act(_conv(weights, x, wk, pol, stride), out_pol)
     oh = 8 // stride
     assert EG.is_prequant(fused) and fused["m"].dtype == jnp.int8
     assert fused["m"].shape == (2, oh, oh, 16)

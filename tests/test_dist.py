@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.configs.base import SHAPES, reduced
+from repro.configs.base import reduced
 from repro.configs.registry import ARCHS
 from repro.dist import compress, sharding, specs
 from repro.launch.mesh import make_mesh
@@ -90,13 +90,51 @@ def test_param_and_cache_specs_structure():
         jax.tree_util.tree_structure(cache_sds)
 
 
-def test_build_cell_lowers_with_specs():
-    """input_specs.build_cell consumes dist.specs without device work."""
-    from repro.launch.input_specs import build_cell
+def test_specs_lower_under_jit():
+    """The spec trees are shardings ``jax.jit`` accepts: a reduced
+    tinyllama train step (parameters and AdamW moments under
+    ``param_specs``) and a decode step (the cache under ``cache_specs``)
+    lower on a (1, 1) mesh under ``axis_rules``, without device work."""
+    from jax.sharding import NamedSharding
+    from repro.models.lm import model as Mdl
+    from repro.optim import optimizers as opt
+    from repro.train.step import TrainState, make_train_step
     cfg = reduced(ARCHS["tinyllama-1.1b"])
     mesh = make_mesh((1, 1), ("data", "model"))
-    cell = build_cell(cfg, SHAPES["train_4k"], mesh)
-    assert len(cell.args) == len(cell.in_specs)
+
+    def named(tree):
+        return jax.tree_util.tree_map(lambda s: NamedSharding(mesh, s), tree,
+                                      is_leaf=lambda x: isinstance(x, P))
+
+    params = jax.eval_shape(lambda: Mdl.init_params(cfg, KEY))
+    pspecs = specs.param_specs(cfg, params, mesh)
+    state = TrainState(params=params,
+                       opt_state=jax.eval_shape(opt.adamw_init, params),
+                       step=jax.ShapeDtypeStruct((), jnp.int32))
+    sspecs = TrainState(params=pspecs,
+                        opt_state=opt.OptState(step=P(), mu=pspecs,
+                                               nu=pspecs),
+                        step=P())
+    tokens = jax.ShapeDtypeStruct((4, 16), jnp.int32)
+    cache = jax.eval_shape(lambda: Mdl.init_cache(cfg, 4, 64))
+    cspecs = specs.cache_specs(cfg, cache, mesh)
+    rows = P("data", None)
+    with sharding.axis_rules(sharding.DEFAULT_RULES, mesh):
+        train = jax.jit(make_train_step(cfg, opt.constant_schedule(1e-4)),
+                        in_shardings=named((sspecs, (rows, rows))),
+                        out_shardings=(named(sspecs), None)
+                        ).lower(state, (tokens, tokens))
+        decode = jax.jit(
+            lambda p, c, t, pos: Mdl.decode_step(p, cfg, c, t, pos),
+            in_shardings=named((pspecs, cspecs, rows, P())),
+            out_shardings=(None, named(cspecs)),
+        ).lower(params, cache, jax.ShapeDtypeStruct((4, 1), jnp.int32),
+                jax.ShapeDtypeStruct((), jnp.int32))
+    for low, tree in ((train, (state, (tokens, tokens))),
+                      (decode, (params, cache))):
+        assert "sharding" in low.as_text()
+        n_in = len(jax.tree_util.tree_leaves(low.args_info))
+        assert n_in >= len(jax.tree_util.tree_leaves(tree))
 
 
 def test_quantize_leaf_shapes_and_snr():

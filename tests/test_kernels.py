@@ -185,27 +185,36 @@ def test_matmul_kernel_property(b, kt, n, bits, scale_pow, seed):
                                rtol=1e-6, atol=1e-30)
 
 # ---------------------------------------------------------------------------
-# ISSUE 6 — dot-mode datapaths, pipelining, fused requantize epilogue
+# ISSUE 6 — dot-mode datapaths, fused requantize epilogue
 # ---------------------------------------------------------------------------
 
-from repro.core.prequant import dequantize_act, is_prequant, prequant_act  # noqa: E402
+from repro.core.prequant import (dequantize_act, is_prequant,  # noqa: E402
+                                 prequant_act, prequant_leaf)
 from repro.kernels.bfp_matmul import f32_dot_exact, resolve_dot_impl  # noqa: E402
 
 
+def _matmul(weights, x, w, pol, **kw):
+    """``ops`` GEMM with float weights (the fused kernel) or with the
+    weights prequantized (the sidecar kernel a bound plan launches)."""
+    if weights == "float":
+        return ops.bfp_matmul(x, w, pol, True, **kw)
+    pq = prequant_leaf(w, pol)
+    return ops.bfp_matmul_prequant(x, pq["m"], pq["s"], pol, True, **kw)
+
+
 @pytest.mark.parametrize("dot_impl", ["int8", "int32", "f32"])
-@pytest.mark.parametrize("pipeline", [False, True])
-def test_matmul_dot_modes_bit_identical(dot_impl, pipeline):
-    """Every dot datapath x pipelining matches the oracle AND the legacy
-    int32/unpipelined kernel bit for bit (f32 is exact at bk=128, L=8:
-    128 * 127 * 127 < 2^24; int8 products widen to int32 in the MXU)."""
+@pytest.mark.parametrize("weights", ["float", "prequant"])
+def test_matmul_dot_modes_bit_identical(dot_impl, weights):
+    """Every dot datapath, with float or prequantized weights, matches
+    the oracle AND the int32 datapath on the same weight form bit for bit
+    (f32 is exact at bk=128, L=8: 128 * 127 * 127 < 2^24; int8 products
+    widen to int32 in the MXU)."""
     x = _rand(jax.random.PRNGKey(30), (64, 384), jnp.float32, 2.0)
     w = _rand(jax.random.PRNGKey(31), (384, 48), jnp.float32, 0.1)
     pol = BFPPolicy(scheme=Scheme.TILED, block_k=128,
                     straight_through=False)
-    out = ops.bfp_matmul(x, w, pol, True, dot_impl=dot_impl,
-                         pipeline=pipeline)
-    base = ops.bfp_matmul(x, w, pol, True, dot_impl="int32",
-                          pipeline=False)
+    out = _matmul(weights, x, w, pol, dot_impl=dot_impl)
+    base = _matmul(weights, x, w, pol, dot_impl="int32")
     np.testing.assert_array_equal(np.asarray(out), np.asarray(base))
     out_r = ref.bfp_matmul_ref(x, w, 8, 8, 128)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out_r),
@@ -266,21 +275,20 @@ def test_matmul_tiles_are_performance_only(tiles):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(base))
 
 
-@pytest.mark.parametrize("pipeline", [False, True])
+@pytest.mark.parametrize("weights", ["float", "prequant"])
 @pytest.mark.parametrize("bq,n", [(8, 64), (16, 48), (8, 72)])
-def test_matmul_epilogue_requant_bit_identical(pipeline, bq, n):
+def test_matmul_epilogue_requant_bit_identical(weights, bq, n):
     """Fused epilogue requantization == dequantize-then-prequant_act,
-    bit for bit, across out-block sizes and an N the default bn does
-    not divide (which exercises the two-step fallback inside ops)."""
+    bit for bit, with float or prequantized weights, across out-block
+    sizes and an N the default bn does not divide (which exercises the
+    two-step fallback inside ops)."""
     x = _rand(jax.random.PRNGKey(36), (64, 256), jnp.float32, 2.0)
     w = _rand(jax.random.PRNGKey(37), (256, n), jnp.float32, 0.1)
     pol = BFPPolicy(scheme=Scheme.TILED, block_k=128,
                     straight_through=False)
     out_pol = pol.with_(block_k=bq)
-    fused = ops.bfp_matmul(x, w, pol, True, out_policy=out_pol,
-                           pipeline=pipeline)
-    two = prequant_act(ops.bfp_matmul(x, w, pol, True, pipeline=pipeline),
-                       out_pol)
+    fused = _matmul(weights, x, w, pol, out_policy=out_pol)
+    two = prequant_act(_matmul(weights, x, w, pol), out_pol)
     assert is_prequant(fused) and fused["m"].dtype == jnp.int8
     assert fused["m"].shape == (64, n)
     assert fused["s"].shape == (64, n // bq)

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from repro.configs.base import reduced
-from repro.configs.registry import ARCHS, cells
+from repro.configs.registry import ARCHS
 from repro.core.policy import PAPER_DEFAULT
 from repro.models.lm import common as C, model as Mdl
 
@@ -164,18 +164,6 @@ def test_moe_capacity_drops_counted():
     out, aux = M.moe_apply(p, cfg, x)
     assert out.shape == x.shape and bool(jnp.all(jnp.isfinite(out)))
     assert float(aux) > 0.5  # aux loss active
-
-
-def test_cells_accounting():
-    """40 assigned cells: 33 runnable + 7 documented long_500k skips."""
-    cs = cells()
-    assert len(cs) == 40
-    skips = [c for c in cs if c[2] is not None]
-    assert len(skips) == 7
-    assert all(c[1] == "long_500k" for c in skips)
-    runnable_long = [c for c in cs if c[1] == "long_500k" and c[2] is None]
-    assert sorted(c[0] for c in runnable_long) == [
-        "mixtral-8x7b", "recurrentgemma-9b", "rwkv6-3b"]
 
 
 def test_param_count_matches_analytic():

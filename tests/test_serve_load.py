@@ -1,6 +1,6 @@
 """Open-loop load harness (ISSUE 9): deterministic Poisson traces, the
 virtual-clock driver, report accounting, and the continuous-vs-bucket
-ordering the pinned BENCH_serve.json trajectory gates.
+ordering on a virtual clock.
 """
 import jax
 import numpy as np

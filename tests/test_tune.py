@@ -8,7 +8,7 @@ Contracts:
     change a bit of output, so a stale/wrong cache entry can cost speed
     but not correctness;
   * tune_gemm/tune_conv hillclimb within budget, store the winner, and
-    skip already-cached sites (the launch/hillclimb.py shape).
+    skip already-cached sites.
 """
 import json
 

@@ -1,24 +1,13 @@
 #!/usr/bin/env python
-"""Gate the pinned benchmark trajectories (ISSUE 6 / ISSUE 9).
+"""Gate the pinned packed-container byte ratios (``BENCH_pack.json``).
 
-    python tools/check_bench.py BENCH_kernels.json bench-kernels-ci.json
-    python tools/check_bench.py BENCH_serve.json   bench-serve-ci.json
+    python tools/check_bench.py BENCH_pack.json bench-pack-ci.json
 
-Compares a freshly-measured ``--bench-json`` artifact against the
-committed baseline:
+Compares a freshly-measured ``benchmarks.pack_bench --bench-json``
+artifact against the committed baseline:
 
-  * ratio fields (``speedup`` — legacy us / new us for kernel records,
-    p99 bucket/continuous for serve records — and, where present,
-    ``goodput_ratio``) may not regress by more than 20% for any record —
-    ratios of two measurements on the SAME machine in the SAME mode are
-    machine-independent, so this gate works on any CI runner even though
-    absolute microseconds do not transfer (the serve-load ratios are
-    computed on a deterministic virtual clock and reproduce exactly);
-  * ``hbm_bytes`` (and the epilogue activation-bytes model), when the
-    record carries them, must match EXACTLY — these are derived from
-    shapes, not measured, so any drift means the benchmarked problem
-    changed out from under the baseline.  Serve records have no byte
-    model and simply omit the field;
+  * each record's ``speedup`` field — fixed-L bytes / variable-width
+    bytes on the same parameters — may not regress by more than 20%;
   * every baseline record must still be present (same kind + name).
 
 Exit status 1 on any failure, with a per-record report either way.
@@ -29,9 +18,6 @@ import json
 import sys
 
 TOLERANCE = 0.20  # max allowed relative ratio regression
-
-#: gated ratio fields, checked when present in the baseline record
-RATIO_FIELDS = ("speedup", "goodput_ratio")
 
 
 def _key(rec):
@@ -58,29 +44,18 @@ def check(base_doc: dict, new_doc: dict) -> list:
         if n is None:
             failures.append(f"{tag}: record missing from candidate")
             continue
-        if "hbm_bytes" in b and b["hbm_bytes"] != n.get("hbm_bytes"):
-            failures.append(f"{tag}: hbm_bytes changed "
-                            f"{b['hbm_bytes']} -> {n.get('hbm_bytes')} "
-                            f"(benchmarked problem drifted)")
-        if "epilogue" in b:
-            for f in ("act_bytes_f32", "act_bytes_wire"):
-                if b["epilogue"][f] != n.get("epilogue", {}).get(f):
-                    failures.append(f"{tag}: epilogue {f} changed")
-        for field in RATIO_FIELDS:
-            if field not in b:
-                continue
-            got = n.get(field)
-            if got is None:
-                failures.append(f"{tag}: {field} missing from candidate")
-                continue
-            floor = b[field] * (1 - TOLERANCE)
-            status = "ok" if got >= floor else "FAIL"
-            print(f"{status:4s} {tag:32s} {field} {b[field]:6.2f}x -> "
-                  f"{got:6.2f}x (floor {floor:.2f}x)")
-            if status == "FAIL":
-                failures.append(
-                    f"{tag}: {field} regressed {b[field]:.2f}x -> "
-                    f"{got:.2f}x (> {TOLERANCE:.0%} drop)")
+        got = n.get("speedup")
+        if got is None:
+            failures.append(f"{tag}: speedup missing from candidate")
+            continue
+        floor = b["speedup"] * (1 - TOLERANCE)
+        status = "ok" if got >= floor else "FAIL"
+        print(f"{status:4s} {tag:32s} speedup {b['speedup']:6.2f}x -> "
+              f"{got:6.2f}x (floor {floor:.2f}x)")
+        if status == "FAIL":
+            failures.append(
+                f"{tag}: speedup regressed {b['speedup']:.2f}x -> "
+                f"{got:.2f}x (> {TOLERANCE:.0%} drop)")
     return failures
 
 
